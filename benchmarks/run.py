@@ -9,6 +9,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         fig2_rank_sweep,
         fig3_quantizer,

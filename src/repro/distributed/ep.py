@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.jaxcompat import get_abstract_mesh, shard_map
-
 
 def _capacity(n_tokens: int, top_k: int, n_experts: int, factor: float) -> int:
     c = int(n_tokens * top_k * factor / max(1, n_experts))
@@ -67,7 +65,7 @@ def experts_ep(cfg, p, x, weights, top_idx, axis: str = "model",
     int32 count of (token, slot) assignments past expert capacity this
     call (the same psum the combine already needs; no extra collective)."""
     axis = axis or "model"
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     tp = mesh.shape[axis]
     e_total = cfg.n_experts
     e_local = e_total // tp
@@ -118,7 +116,7 @@ def experts_ep(cfg, p, x, weights, top_idx, axis: str = "model",
         P(),
         jax.tree.map(lambda _: _expert_spec(axis), p["experts"]),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=in_specs,

@@ -68,8 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.jaxcompat import get_abstract_mesh, make_mesh, shard_map
 from repro.distributed.sharding import param_pspecs, to_shardings
+from repro.launch.mesh import auto_mesh
 from repro.quant.qlinear import QLinear
 
 
@@ -102,7 +102,7 @@ def build_mesh(spec) -> Mesh:
         raise ValueError(
             f"mesh {dict(spec)} needs {need} devices, have {have} "
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU)")
-    return make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def _axis_size(mesh, axis: str) -> int:
@@ -198,9 +198,9 @@ def tp_qlinear_apply(q: QLinear, x: jnp.ndarray, axis: str = "model"):
     from repro.quant.qlinear import qlinear_apply
 
     kind = q.parallel
-    mesh = get_abstract_mesh()
-    tp = _axis_size(mesh, axis) if mesh is not None else 1
-    if mesh is None or kind not in ("column", "row", "replicate") \
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = _axis_size(mesh, axis)
+    if mesh.empty or kind not in ("column", "row", "replicate") \
             or (kind != "replicate" and not tp_feasible(q, kind, tp)):
         return qlinear_apply(_strip(q), x)
 
@@ -239,7 +239,7 @@ def tp_qlinear_apply(q: QLinear, x: jnp.ndarray, axis: str = "model"):
         x_spec = P(*([None] * nlead), axis)
         out_spec = P(*([None] * (nlead + 1)))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, _field_specs(q, kind, axis)),

@@ -34,7 +34,8 @@ def act_quant_kernel(
     clip_ratio: float = 1.0,
     bm: int = 128,
     group: int = None,  # None = per-token; else scales per K group
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     m, k = x.shape
     assert m % bm == 0, (m, bm)
@@ -55,7 +56,7 @@ def act_quant_kernel(
             jax.ShapeDtypeStruct((m, k), jnp.int8),
             jax.ShapeDtypeStruct((m, n_s), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # M tiles are independent
         ),
         interpret=interpret,

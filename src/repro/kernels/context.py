@@ -38,9 +38,9 @@ context).  The old global setters (``load_block_table`` /
 
 Activation-scale granularity rides the same resolution:
 ``resolve_plan(..., act_group=g)`` snaps BK to a power-of-two multiple of
-``g`` (K-chunks must hold whole scale groups), adds the per-group
-(M, K/g) scale plane to the VMEM working-set model, and demotes a path
-only when no multiple-of-``g`` tiling fits — ``explain(...,
+``lcm(g, 128)`` (K-chunks hold whole scale groups and stay lane tiles),
+adds the per-group (M, K/g) scale plane to the VMEM working-set model, and
+demotes a path only when no such tiling fits — ``explain(...,
 act_group=g)`` reports the snap and any granularity-driven demotion.
 """
 
@@ -52,7 +52,8 @@ import json
 from pathlib import Path
 from typing import NamedTuple, Optional
 
-from repro.kernels.rowops import (default_proj_tiles,
+from repro.kernels.rowops import (LANES, default_proj_tiles, k_tile,
+                                  lane_tile,
                                   round_pow2 as _round_pow2,
                                   snap_bk_to_group)
 
@@ -196,13 +197,13 @@ def _fit_fused(k: int, r: int, bm: int, bn: int, bk: int, br: int,
     slab.  ``variant_pin`` restricts the search to one variant (a
     table/override pin); rotation still forces the resident slab.  With
     group-wise scales (``act_group``) BK starts snapped to a power-of-two
-    multiple of the group and can shrink no further than one group — the
-    halving search stays closed over the chunks-hold-whole-groups
+    multiple of ``lcm(g, 128)`` and can shrink no further than that unit —
+    the halving search stays closed over the chunks-hold-whole-groups
     constraint."""
     if act_group is not None:
         bk = snap_bk_to_group(bk, act_group)
-    mins = dict(bk=act_group if act_group is not None else min(bk, 128),
-                br=min(br, 128), bn=min(bn, 128), bm=min(bm, 8))
+    mins = dict(bk=snap_bk_to_group(LANES, act_group) if act_group else LANES,
+                br=LANES, bn=LANES, bm=min(bm, 8))
     variants = ("resident",) if rotate else ("resident", "streamed")
     if variant_pin is not None and not (rotate and variant_pin == "streamed"):
         variants = (variant_pin,)
@@ -223,8 +224,8 @@ def _fit_chained(k: int, r: int, bm: int, bn: int, bk: int, br: int,
     """Feasible chained-path plan under the prologue budget, or None."""
     if act_group is not None:
         bk = snap_bk_to_group(bk, act_group)
-    mins = dict(bk=act_group if act_group is not None else min(bk, 128),
-                br=min(br, 128), bm=min(bm, 8))
+    mins = dict(bk=snap_bk_to_group(LANES, act_group) if act_group else LANES,
+                br=LANES, bm=min(bm, 8))
 
     def bytes_fn(bm, bk, br):
         return prologue_vmem_bytes(k, r, bm, bk, br, rotate,
@@ -571,10 +572,10 @@ class KernelContext:
         if override:
             entry.update(override)
         bm = min(entry["bm"], _round_pow2(max(m, 8)))
-        bn = min(entry["bn"], _round_pow2(max(n, 8)))
-        bk = min(entry["bk"], _round_pow2(max(k, 8)))
+        bn = lane_tile(n, entry["bn"])
+        bk = k_tile(k, entry["bk"])
         if "br" in entry:
-            br = min(entry["br"], _round_pow2(max(r, 8)))
+            br = lane_tile(r, entry["br"])
         else:  # pre-K-split tables: the shared kernel default
             br = default_proj_tiles(k, r)[1]
         if r >= 512:
@@ -603,10 +604,11 @@ class KernelContext:
 
         ``act_group`` (group-wise activation scales, paper Table 2) makes
         the granularity a plan axis: BK snaps to a power-of-two multiple of
-        the group (K-chunks must hold whole scale groups; ``g = K`` pins
-        BK = K, degenerating to per-token), the (M, K/g) scale plane joins
-        the working-set model, and BK shrinks no further than one group —
-        a path demotes when no multiple-of-g tiling fits its budget."""
+        ``lcm(g, 128)`` (K-chunks must hold whole scale groups and stay
+        lane tiles; ``g = K`` pins BK = K, degenerating to per-token), the
+        (M, K/g) scale plane joins the working-set model, and BK shrinks no
+        further than that unit — a path demotes when no such tiling fits
+        its budget."""
         if act_group is not None and k % act_group:
             raise ValueError(f"act_group {act_group} must divide K={k}")
         sel = self.select_plan(m, k, n, r, regime=regime, layer=layer)
@@ -659,7 +661,8 @@ class KernelContext:
             lines.append(
                 f"  act_group={act_group}: bk snaps to a multiple of "
                 f"{act_group} (K-chunks hold whole scale groups, floor "
-                f"bk={act_group}); the (M, K/{act_group}) f32 scale plane "
+                f"bk={snap_bk_to_group(LANES, act_group)}); the "
+                f"(M, K/{act_group}) f32 scale plane "
                 f"joins the working set; a path demotes when no such "
                 f"tiling fits its budget")
         override = self.layer_plan(layer, k, n, r)

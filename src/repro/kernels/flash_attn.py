@@ -203,7 +203,8 @@ def paged_flash_attention_kernel(
     block_table: jnp.ndarray,  # (B, MPB) int32 page ids (0 = null page)
     lengths: jnp.ndarray,      # (B,) int32 valid kv count, incl. current token
     scale: float,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Decode attention against a PAGED KV pool (the serving engine's cache
     layout): each sequence reads its pages through its block-table row, so
@@ -246,7 +247,8 @@ def flash_attention_kernel(
     causal: bool = True,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     bh, sq, d = q.shape
     skv = k.shape[1]
@@ -279,7 +281,8 @@ def flash_attention_quant_kernel(
     causal: bool = True,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """``flash_attention_kernel`` over quantized K/V: the scale planes ride
     as two extra inputs blocked exactly like their data tensors, and each
@@ -319,7 +322,8 @@ def paged_flash_attention_quant_kernel(
     scale: float,
     group: int,
     packed: bool,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """``paged_flash_attention_kernel`` over a QUANTIZED page pool: the
     scale-plane sidecar pools ride as two extra inputs under the same

@@ -78,10 +78,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.rowops import (
     amax_to_scale,
     default_proj_tiles,
+    f32_dot,
     fwht_cross_rows,
     fwht_intra_rows,
     gemm_chunk_grouped,
     group_amax,
+    int_dot,
     project_chunk_rows,
     quantize_rows,
     quantize_rows_grouped,
@@ -100,7 +102,6 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
     kk = pl.program_id(2)
     rr = pl.program_id(3)
     last_kr = (kk == n_k - 1) & (rr == n_r - 1)
-    ngc = None if group is None else bk // group  # scale groups per chunk
 
     # ---- prologue sweep (N-visit 0) -------------------------------------
     if resident:
@@ -122,9 +123,15 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
                 sx_s[...] = s
                 xq_s[...] = quantize_rows(row, s, qmax)
             else:
-                s = amax_to_scale(group_amax(row, group), qmax, clip_ratio)
-                sx_s[...] = s
-                xq_s[...] = quantize_rows_grouped(row, s, qmax, group)
+                # groups never cross a chunk: scale and quantize chunk by
+                # chunk into the (n_k, bm, bk // g) scale-plane scratch
+                for c in range(n_k):
+                    chunk = row[:, c * bk:(c + 1) * bk]
+                    s = amax_to_scale(group_amax(chunk, group), qmax,
+                                      clip_ratio)
+                    sx_s[c] = s
+                    xq_s[:, c * bk:(c + 1) * bk] = quantize_rows_grouped(
+                        chunk, s, qmax, group)
     elif group is None:
         @pl.when((j == 0) & (rr == 0))
         def _fold_amax():
@@ -145,14 +152,12 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
         @pl.when((j == 0) & (rr == 0))
         def _group_scales():
             a = group_amax(x_ref[...].astype(jnp.float32), group)
-            sx_s[:, pl.ds(kk * ngc, ngc)] = \
-                amax_to_scale(a, qmax, clip_ratio)
+            sx_s[kk] = amax_to_scale(a, qmax, clip_ratio)
 
         @pl.when((j == 1) & (rr == 0))
         def _quantize_chunk_grouped():
             xq_s[:, pl.ds(kk * bk, bk)] = quantize_rows_grouped(
-                x_ref[...].astype(jnp.float32),
-                sx_s[:, pl.ds(kk * ngc, ngc)], qmax, group)
+                x_ref[...].astype(jnp.float32), sx_s[kk], qmax, group)
 
     # ---- low-rank projection rides the first GEMM visit (V streams) -----
     if xv_s is not None:
@@ -173,16 +178,12 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
 
         w_blk = unpack_int4_rows(wp_ref[...])
         if group is None:
-            acc_s[...] += jax.lax.dot_general(
-                xq_s[:, pl.ds(kk * bk, bk)], w_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
+            acc_s[...] += int_dot(xq_s[:, pl.ds(kk * bk, bk)], w_blk)
         else:
             # dequant in the K loop: the chunk's groups rescale before the
             # f32 accumulation (canonical gemm_chunk_grouped order)
             acc_s[...] += gemm_chunk_grouped(
-                xq_s[:, pl.ds(kk * bk, bk)], w_blk,
-                sx_s[:, pl.ds(kk * ngc, ngc)], group)
+                xq_s[:, pl.ds(kk * bk, bk)], w_blk, sx_s[kk], group)
 
     # ---- epilogue: one HBM write per (M-tile, N-tile) --------------------
     @pl.when((j >= 1) & last_kr)
@@ -192,11 +193,7 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
         else:
             out = acc_s[...] * sw_ref[...]  # activation scales already in
         if xv_s is not None:
-            out = out + jax.lax.dot_general(
-                xv_s[...], u_ref[...].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            out = out + f32_dot(xv_s[...], u_ref[...], b_contract=1)
         out_ref[...] = out
 
 
@@ -217,10 +214,11 @@ def fused_w4a4_lrc_kernel(
     bm: int = 128,
     bn: int = 128,
     bk: int = 256,
-    br: int = None,  # R-tile of the streamed V (defaults: 512-capped pow2)
+    br: int = None,  # R-tile of the streamed V (default: rowops lane tile)
     variant: str = "resident",  # resident | streamed prologue (see module doc)
     act_group: int = None,  # None = per-token scales; else bk % act_group == 0
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """One pallas call for the whole W4A4+LRC forward; returns (M, N) f32."""
     m, k = x.shape
@@ -242,7 +240,6 @@ def fused_w4a4_lrc_kernel(
         # zero groups whose guarded scale quantizes them to 0
         assert k % act_group == 0, (k, act_group)
         assert bk % act_group == 0, (bk, act_group)
-    n_s_pad = 1 if act_group is None else k_pad // act_group
     qmax = 2 ** (bits - 1) - 1
     with_lr = v is not None
 
@@ -295,8 +292,10 @@ def fused_w4a4_lrc_kernel(
     scratch = [
         pltpu.VMEM((bm, k_pad), jnp.int8),  # xq residency
         # sx: per-token column (amax accumulator first on the streamed
-        # sweep) or the per-group scale plane
-        pltpu.VMEM((bm, n_s_pad), jnp.float32),
+        # sweep) or the per-group scale plane, one (bm, bk // g) slab per
+        # K-chunk so the GEMM visit indexes it on the leading dim
+        pltpu.VMEM((bm, 1) if act_group is None
+                   else (n_k, bm, bk // act_group), jnp.float32),
     ]
     if with_lr:
         in_specs.append(pl.BlockSpec(
@@ -345,7 +344,7 @@ def fused_w4a4_lrc_kernel(
         # M tiles are independent (megacore-splittable); the N/K/R visits of
         # one M tile share the prologue's scratch residency and the partial
         # sums, and must stay sequential.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
         ),

@@ -24,7 +24,7 @@ def _kernel(x_ref, o_ref, *, d: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def fwht_kernel(x: jnp.ndarray, bm: int = 256, interpret: bool = True):
+def fwht_kernel(x: jnp.ndarray, bm: int = 256, *, interpret: bool):
     """x: (M, D) with D a power of two; returns x @ H_D (normalized)."""
     m, d = x.shape
     assert d & (d - 1) == 0, d
@@ -35,7 +35,7 @@ def fwht_kernel(x: jnp.ndarray, bm: int = 256, interpret: bool = True):
         in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # M tiles are independent
         ),
         interpret=interpret,

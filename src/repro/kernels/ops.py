@@ -73,8 +73,7 @@ from repro.kernels.context import (
 from repro.kernels.fused_gemm import fused_w4a4_lrc_kernel
 from repro.kernels.hadamard import fwht_kernel
 from repro.kernels.prologue import fused_prologue_kernel
-from repro.kernels.rowops import (project_rows_tiled,
-                                  round_pow2 as _round_pow2,
+from repro.kernels.rowops import (lane_tile, project_rows_tiled,
                                   snap_bk_to_group)
 from repro.kernels.w4a4 import w4a4_lowrank_matmul_kernel
 from repro.kernels.flash_attn import (flash_attention_kernel,
@@ -192,8 +191,8 @@ def resolve_plan(m: int, k: int, n: int, r: int = 0, rotate: bool = False,
     block-table plan with per-slab VMEM feasibility applied — tiles shrink
     to fit the budget first; the path demotes (fused → chained → unfused)
     only when no tiling fits.  ``act_group`` (per-group activation scales)
-    snaps BK to a multiple of the group and adds the (M, K/g) scale plane
-    to the working-set model."""
+    snaps BK to a lane-tile multiple of the group and adds the (M, K/g)
+    scale plane to the working-set model."""
     return _ctx(ctx).resolve_plan(m, k, n, r, rotate=rotate, regime=regime,
                                   layer=layer, act_group=act_group)
 
@@ -384,7 +383,7 @@ def w4a4_lrc_forward(
         bm, bn, bk = blocks[:3]
         if len(blocks) > 3:
             br = blocks[3]
-        br = min(br, _round_pow2(max(r, 8)))
+        br = lane_tile(r, br)
         variant = None
     if group is not None:
         bk = snap_bk_to_group(bk, group)  # K-chunks hold whole scale groups

@@ -100,7 +100,8 @@ def fused_prologue_kernel(
     bk: int = None,  # V-stream K-chunk (defaults per default_proj_tiles)
     br: int = None,  # V-stream R-tile
     act_group: int = None,  # None = per-token scales; else one per K group
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """One grid pass over row tiles: returns (xq int8, sx f32[, xv f32]).
 
@@ -137,7 +138,7 @@ def fused_prologue_kernel(
                 jax.ShapeDtypeStruct((m, k), jnp.int8),
                 jax.ShapeDtypeStruct((m, n_s), jnp.float32),
             ],
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
         )(x)
@@ -191,7 +192,7 @@ def fused_prologue_kernel(
         scratch_shapes=scratch,
         # M tiles are independent; the (kk, rr) visits of one M tile share
         # the xv block residency and must stay sequential.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, vp)

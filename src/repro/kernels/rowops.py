@@ -55,8 +55,16 @@ the same scalar ops on the same operands.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+
+
+# TPU vreg lane width.  Mosaic needs the last dim of every block to be a
+# multiple of it (or the whole array dim), so K, N and R tiles are lane
+# multiples; M tiles only need the 8-row sublane multiple round_pow2 gives.
+LANES = 128
 
 
 def round_pow2(m: int) -> int:
@@ -67,16 +75,34 @@ def round_pow2(m: int) -> int:
     return p
 
 
+def lane_tile(dim: int, cap: int) -> int:
+    """Power-of-two lane tile for an N or R extent: ``cap`` clamped to the
+    problem, never below one lane width (short extents pad up to it)."""
+    return min(cap, max(LANES, round_pow2(dim)))
+
+
+def k_tile(k: int, cap: int) -> int:
+    """K-chunk tile: a lane tile, except that K below one lane width is
+    ONE whole chunk (online rotation needs K unpadded).  Layers that narrow
+    exist only at test sizes, which run interpreted."""
+    if k >= LANES:
+        return lane_tile(k, cap)
+    p = 8
+    while p < k:
+        p *= 2
+    return p
+
+
 def default_proj_tiles(k: int, r: int, bk=None, br=None):
-    """Default (bk, br) projection tiles: 512-capped powers of two clamped
-    to the problem.  THE one spelling of the default — the prologue and
-    fused kernels and the ops-layer plan table all derive their fallback
-    tiles from here, so direct kernel callers and the dispatched paths
-    agree on the (bk, br) accumulation order the bitwise contract needs."""
+    """Default (bk, br) projection tiles: 512-capped lane tiles.  THE one
+    spelling of the default — the prologue and fused kernels and the
+    ops-layer plan table all derive their fallback tiles from here, so
+    direct kernel callers and the dispatched paths agree on the (bk, br)
+    accumulation order the bitwise contract needs."""
     if bk is None:
-        bk = min(512, round_pow2(max(k, 8)))
+        bk = k_tile(k, 512)
     if br is None:
-        br = min(512, round_pow2(max(r, 8)))
+        br = lane_tile(r, 512)
     return bk, br
 
 
@@ -138,34 +164,53 @@ def row_amax(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def snap_bk_to_group(bk: int, group: int) -> int:
-    """Largest ``group · 2^j ≤ bk`` (minimum ``group``): with group-wise
-    activation scales a K-chunk must hold WHOLE scale groups, and the
+    """Largest ``unit · 2^j ≤ bk`` (minimum one unit): with group-wise
+    activation scales a K-chunk must hold WHOLE scale groups, so the unit
+    is ``group`` — and ``lcm(group, LANES)`` once chunks are lane tiles
+    (``bk ≥ LANES``; a shorter bk is the one whole-K chunk).  The
     power-of-two multiple keeps the plan layer's halving shrink-to-fit
-    closed over the constraint (every halving above ``group`` is still a
-    multiple of ``group``)."""
-    snapped = group
+    closed over the constraint (every halving above the unit is still a
+    multiple of it)."""
+    snapped = group if bk < LANES else math.lcm(group, LANES)
     while snapped * 2 <= bk:
         snapped *= 2
     return snapped
 
 
+def _lane_groups(shape, group: int) -> jnp.ndarray:
+    """Scale-group id of every element of a (bm, d) tile: lane // group."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // group
+
+
 def group_amax(x: jnp.ndarray, group: int) -> jnp.ndarray:
     """Per-group |x| max of a (bm, d) tile -> (bm, d // group).  Groups are
     contiguous along K; because chunks hold whole groups, chunk-wise
-    application computes exactly the whole-row result."""
+    application computes exactly the whole-row result.  Each group's max
+    reduces the full-width tile with the other groups masked to 0 (|x| ≥ 0,
+    so the mask is exact): Mosaic cannot split the lane dim into groups."""
     bm, d = x.shape
     assert d % group == 0, (d, group)
-    return jnp.max(jnp.abs(x.reshape(bm, d // group, group)), axis=-1)
+    ax = jnp.abs(x)
+    gid = _lane_groups(x.shape, group)
+    pid = _lane_groups((bm, d // group), 1)
+    plane = jnp.zeros((bm, d // group), x.dtype)
+    for gi in range(d // group):
+        a = jnp.max(jnp.where(gid == gi, ax, 0.0), axis=-1, keepdims=True)
+        plane = jnp.where(pid == gi, a, plane)
+    return plane
 
 
 def quantize_rows_grouped(x: jnp.ndarray, s: jnp.ndarray, qmax: int,
                           group: int) -> jnp.ndarray:
     """Elementwise q = clip(round(x/s)) with one scale per K group.  Safe to
-    apply per chunk with the matching slice of the scale plane."""
+    apply per chunk with the matching slice of the scale plane.  The plane
+    is broadcast to the tile by selects (exact), not by a lane reshape."""
     bm, d = x.shape
-    xs = x.reshape(bm, d // group, group) / s[..., None]
-    return jnp.clip(jnp.round(xs), -qmax - 1, qmax) \
-        .astype(jnp.int8).reshape(bm, d)
+    gid = _lane_groups(x.shape, group)
+    se = jnp.zeros_like(x)
+    for gi in range(d // group):
+        se = jnp.where(gid == gi, s[:, gi:gi + 1], se)
+    return jnp.clip(jnp.round(x / se), -qmax - 1, qmax).astype(jnp.int8)
 
 
 def amax_to_scale(amax: jnp.ndarray, qmax: int, clip_ratio: float):
@@ -203,39 +248,43 @@ def gemm_chunk_grouped(xq_chunk: jnp.ndarray, w_chunk: jnp.ndarray,
     identical across paths (cross-chunk accumulation is ascending-K f32
     adds of these per-chunk results).
 
-    The rescale-and-sum over the chunk's groups is ONE ``dot_general``
-    contraction (out[m, n] = Σ_g s[m, g] · d[g, m, n]) rather than an
-    unrolled mul/add chain — this is load-bearing for the bitwise
-    contract: XLA contracts a hand-written ``prev + acc·s`` chain into an
-    FMA in one kernel's compilation and not another's, skewing the last
-    bit between paths, while the same-shape dot lowers identically in
-    every compilation unit (the xv projection's parity rests on the same
-    property)."""
-    bm, bk = xq_chunk.shape
-    n_g = bk // group
-    parts = [
-        jax.lax.dot_general(
-            xq_chunk[:, gi * group:(gi + 1) * group],
-            w_chunk[gi * group:(gi + 1) * group, :],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        for gi in range(n_g)  # exact int32 group partials, ascending K
-    ]
-    stacked = jnp.stack(parts, axis=1).astype(jnp.float32)  # (bm, n_g, bn)
+    The rescale-and-sum is an unrolled multiply-add chain in ascending
+    group order: Mosaic cannot lower the batched ``dot_general`` that once
+    carried it.  All three paths call this one body, and the interpret-mode
+    parity tests pin that they still agree bit for bit."""
+    out = None
+    for gi in range(xq_chunk.shape[1] // group):  # ascending K
+        part = int_dot(xq_chunk[:, gi * group:(gi + 1) * group],
+                       w_chunk[gi * group:(gi + 1) * group, :])
+        term = part.astype(jnp.float32) * s_chunk[:, gi:gi + 1]
+        out = term if out is None else out + term
+    return out
+
+
+def int_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """(m, k) int8 × (k, n) int8 → exact (m, n) int32.  The precision is
+    pinned: under a caller's ``default_matmul_precision("highest")`` Mosaic
+    would be asked for an f32 contraction of integer operands, and refuses."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.int32)
+
+
+def f32_dot(a: jnp.ndarray, b: jnp.ndarray, b_contract: int = 0):
+    """f32 contraction of a's last dim with b's dim ``b_contract``, at
+    full f32 precision on every backend whatever the caller's default
+    matmul precision (the low-rank term is computed in f32)."""
     return jax.lax.dot_general(
-        s_chunk, stacked, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        (((1,), (b_contract,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def project_chunk_rows(x_chunk: jnp.ndarray, v_tile: jnp.ndarray):
     """ONE (bm, bk) × (bk, br) projection partial — the canonical dot every
     path issues per (K-chunk, R-tile).  f32 in, f32 out."""
-    return jax.lax.dot_general(
-        x_chunk, v_tile.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    return f32_dot(x_chunk, v_tile)
 
 
 def project_rows_tiled(x: jnp.ndarray, v: jnp.ndarray, bk: int, br: int):
@@ -271,10 +320,7 @@ def prologue_rows(x, v, qmax: int, clip_ratio: float, rotate: bool, d: int,
     q, s = scale_round_quantize(x, qmax, clip_ratio, group=group)
     xv = None
     if v is not None:
-        xv = jax.lax.dot_general(
-            x, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        xv = f32_dot(x, v)
     return q, s, xv
 
 
@@ -295,11 +341,13 @@ def dequant_rows_grouped(q: jnp.ndarray, s: jnp.ndarray,
 
 def unpack_int4_rows(wp: jnp.ndarray) -> jnp.ndarray:
     """(BK//2, BN) uint8 -> (BK, BN) int8 in [-8, 7]; even rows = low nibble.
-    Packed rows interleave (2i, 2i+1): stack on a new axis, then fold."""
-    lo = (wp & 0xF).astype(jnp.int8)
-    hi = ((wp >> 4) & 0xF).astype(jnp.int8)
+    Packed rows interleave (2i, 2i+1): stack on a new axis, then fold.  The
+    nibbles are shifted out as int32: Mosaic has no 8-bit vector shift."""
+    w = wp.astype(jnp.int32)
+    lo = w & 0xF
+    hi = (w >> 4) & 0xF
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
     bk2, bn = wp.shape
     w = jnp.stack([lo, hi], axis=1)  # (BK//2, 2, BN)
-    return w.reshape(bk2 * 2, bn)
+    return w.reshape(bk2 * 2, bn).astype(jnp.int8)

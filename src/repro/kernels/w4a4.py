@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.rowops import (gemm_chunk_grouped,
+from repro.kernels.rowops import (f32_dot, gemm_chunk_grouped, int_dot,
                                   unpack_int4_rows as _unpack_block)
 
 
@@ -52,10 +52,7 @@ def _body(xq_ref, sx_ref, wp_ref, sw_ref, xv_ref, u_ref, out_ref, acc_ref, *,
 
     w_blk = _unpack_block(wp_ref[...])  # (BK, BN) int8
     if group is None:
-        acc_ref[...] += jax.lax.dot_general(
-            xq_ref[...], w_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        acc_ref[...] += int_dot(xq_ref[...], w_blk)
     else:
         # dequant in the K loop: this chunk's groups rescaled before the
         # f32 accumulation (canonical order shared with the fused kernel)
@@ -69,13 +66,7 @@ def _body(xq_ref, sx_ref, wp_ref, sw_ref, xv_ref, u_ref, out_ref, acc_ref, *,
         else:
             out = acc_ref[...] * sw_ref[...]  # activation scales already in
         if xv_ref is not None:
-            lr = jax.lax.dot_general(
-                xv_ref[...].astype(jnp.float32),
-                u_ref[...].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            out = out + lr
+            out = out + f32_dot(xv_ref[...], u_ref[...], b_contract=1)
         out_ref[...] = out
 
 
@@ -106,7 +97,8 @@ def w4a4_lowrank_matmul_kernel(
     bn: int = 128,
     bk: int = 256,
     group: int = None,  # None = per-token scales; else BK % group == 0
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     m, k = xq.shape
     n = wpacked.shape[1]
@@ -120,7 +112,10 @@ def w4a4_lowrank_matmul_kernel(
         assert bk % group == 0, (bk, group)  # chunks hold whole groups
         assert sx.shape[1] == k // group, (sx.shape, k, group)
         n_sb = bk // group  # this chunk's slice of the scale plane
-        sx_spec = pl.BlockSpec((bm, n_sb), lambda i, j, kk: (i, kk))
+        # (n_k, M, bk // g): a chunk's slice is a whole trailing dim, which
+        # keeps the block legal where bk // g is not a lane multiple
+        sx = sx.reshape(m, n_k, n_sb).transpose(1, 0, 2)
+        sx_spec = pl.BlockSpec((None, bm, n_sb), lambda i, j, kk: (kk, i, 0))
 
     grid = (m // bm, n // bn, n_k)
     in_specs = [
@@ -151,7 +146,7 @@ def w4a4_lowrank_matmul_kernel(
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         # Mosaic pipeline: M/N tiles are independent (megacore-splittable);
         # K carries the accumulator and must stay sequential + innermost.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,7 +14,6 @@ import jax  # noqa: E402
 
 from repro.configs import ARCH_IDS, get_config  # noqa: E402
 from repro.configs.shapes import SHAPES, applicable  # noqa: E402
-from repro.core.jaxcompat import set_mesh  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.roofline import (  # noqa: E402
     combine_costs,
@@ -30,7 +29,7 @@ RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
 def _compile_cell(arch, shape_name, mesh, multi_pod, cfg_override=None):
     cell = build_cell(arch, shape_name, mesh, multi_pod, cfg_override=cfg_override)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell["fn"], in_shardings=cell["in_shardings"])
         lowered = jitted.lower(*cell["args"])
         compiled = lowered.compile()

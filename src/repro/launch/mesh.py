@@ -11,15 +11,19 @@ device state (the dry-run must set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-from repro.core.jaxcompat import make_mesh
+import jax
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (jax defaults to
+    ``Explicit``): the repo shards by ``shard_map`` and GSPMD hints, and
+    relies on the compiler propagating shardings across the rest."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for unit tests on forced host devices."""
-    return make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

@@ -1,13 +1,19 @@
 """Serving launcher: batched requests through a (quantized) model.
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
-        [--quantize [--act-group G]] [--requests 8] [--new-tokens 16] \
+        [--reduced] [--quantize [--act-group G]] [--requests 8] \
+        [--new-tokens 16] \
         [--page-size 16] [--kv-pages N] [--prefill-chunk C] \
         [--kv-dtype int8|int4 --kv-group G] \
         [--mesh model=N,data=M] \
         [--block-table results/block_table.json] [--vmem-budget BYTES] \
         [--deadline-s 30] [--retries 2] [--queue-bound 64] \
         [--inject-faults K --fault-seed S --parity-check]
+
+The model is served at its published widths; ``--reduced`` swaps in the
+tiny same-family config (``models.config.reduced``) for CPU smoke runs.
+Outside the chaos harnesses the launcher exits non-zero unless every
+request ends FINISHED.
 
 Mesh-sharded serving (docs/serving.md, "Sharded serving"): ``--mesh``
 builds a device mesh (prod(sizes) must equal the visible device count —
@@ -52,7 +58,7 @@ chaos-smoke step.
 
 Crash-recovery chaos (docs/serving.md, "Crash recovery")::
 
-    PYTHONPATH=src python -m repro.launch.serve --requests 8 \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --requests 8 \
         --journal /tmp/rec/journal.wal --ckpt-dir /tmp/rec \
         --snapshot-every 4 --crash-after 2 [--crash-phase decode] \
         --parity-check
@@ -236,7 +242,9 @@ def main():
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config (CPU smoke "
+                         "runs) instead of the published widths")
     ap.add_argument("--quantize", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -365,6 +373,7 @@ def main():
     import jax
     import numpy as np
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import model as model_lib
     from repro.models.config import reduced as reduce_cfg
     from repro.serve.engine import ServeEngine
@@ -372,6 +381,7 @@ def main():
     from repro.serve.kvquant import KVSpec
     from repro.serve.lifecycle import Request, RequestState
 
+    use_compile_cache()
     ctx = build_context(args.block_table, args.vmem_budget)
     if args.block_table:
         print(f"loaded kernel plan table from {args.block_table}")
@@ -482,7 +492,12 @@ def main():
     _print_failure_summary(done, eng.health(), injector)
 
     ok = True
-    if injector is not None:
+    if injector is None:
+        if len(finished) != len(done):
+            print(f"SERVE FAILED: {len(done) - len(finished)} of {len(done)} "
+                  f"requests did not finish", file=sys.stderr)
+            ok = False
+    else:
         # the acceptance split: exactly K structured failures, N-K clean
         failures = {r.rid for r in done.values()
                     if r.status in (RequestState.FAILED, RequestState.TIMED_OUT)}

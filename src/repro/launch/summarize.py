@@ -67,7 +67,6 @@ def sharding_report(arch: str, mesh_spec: str, use_reduced: bool) -> int:
     import jax
 
     from repro.configs import get_config
-    from repro.core.jaxcompat import abstract_mesh
     from repro.distributed.sharding import describe_sharding
     from repro.distributed.tp import parse_mesh
     from repro.models import model as model_lib
@@ -77,7 +76,9 @@ def sharding_report(arch: str, mesh_spec: str, use_reduced: bool) -> int:
     if use_reduced:
         cfg = reduced(cfg)
     spec = parse_mesh(mesh_spec)
-    mesh = abstract_mesh(tuple(spec.values()), tuple(spec.keys()))
+    mesh = jax.sharding.AbstractMesh(
+        tuple(spec.values()), tuple(spec.keys()),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(spec))
     tree = jax.eval_shape(
         lambda: model_lib.init_params(cfg, jax.random.PRNGKey(0)))
     rows = describe_sharding(tree, mesh)

@@ -43,9 +43,9 @@ def main():
 
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        from repro.core.jaxcompat import make_mesh, set_mesh
+        from repro.launch.mesh import auto_mesh
 
-        mesh = make_mesh((d, m), ("data", "model"))
+        mesh = auto_mesh((d, m), ("data", "model"))
         from repro.distributed.sharding import batch_pspec, param_pspecs, to_shardings
         from repro.train.steps import init_train_state, make_train_step
         from repro.train.optimizer import AdamWState
@@ -53,7 +53,7 @@ def main():
         from repro.data.loader import batches
         from jax.sharding import PartitionSpec as P
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_train_state(cfg, jax.random.PRNGKey(0))
             pspecs = param_pspecs(state.params, mesh, False)
             sspecs = TrainState(params=pspecs,

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.jaxcompat import get_abstract_mesh
 from repro.quant.qlinear import apply_linear
 
 
@@ -68,8 +67,8 @@ def shard_hint(x: jnp.ndarray, axes: tuple) -> jnp.ndarray:
     ``axes`` entries: mesh-axis name (shard, with divisibility guard →
     FREE), None (force replicated), or FREE (leave to GSPMD).
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     P = jax.sharding.PartitionSpec
     spec = []
@@ -95,8 +94,8 @@ def attn_qkv_hints(q, k, v):
         fix, §Perf);
       * decode (q_len == 1) is left to GSPMD (logits are tiny).
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or "model" not in mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.shape:
         return q, k, v
     tp = mesh.shape["model"]
     if q.shape[2] % tp == 0 and k.shape[2] % tp == 0:
@@ -172,11 +171,9 @@ def sharded_attention(q, k, v, mask, scale: float):
     dims — every (row, head) is computed whole on one shard, zero
     collectives in the body), everything replicated otherwise.  Falls back
     to the plain call when no mesh is ambient."""
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return attention(q, k, v, mask, scale)
-    from repro.core.jaxcompat import shard_map
-
     P = jax.sharding.PartitionSpec
     axes = dict(mesh.shape)
     dp, tp = axes.get("data", 1), axes.get("model", 1)
@@ -194,10 +191,10 @@ def sharded_attention(q, k, v, mask, scale: float):
         ins = (qs, kvs, kvs, ms)
         args = (q, k, v, mask)
         fn = lambda ql, kl, vl, ml: attention(ql, kl, vl, ml, scale)
-    out = shard_map(fn, mesh=mesh, in_specs=ins,
-                    out_specs=P(bax, None, hax, None),
-                    check_vma=False,
-                    axis_names={a for a in (bax, hax) if a})(*args)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=ins,
+                        out_specs=P(bax, None, hax, None),
+                        check_vma=False,
+                        axis_names={a for a in (bax, hax) if a})(*args)
     return out
 
 

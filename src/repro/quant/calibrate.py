@@ -355,8 +355,19 @@ def quantize_model(
     resume_dir: Optional[str] = None,
 ):
     """Returns params with policy-selected weights replaced by solved
-    QLinear leaves.  ``calib_tokens``: (n_seq, S) int32."""
-    ensure_x64()
+    QLinear leaves.  ``calib_tokens``: (n_seq, S) int32.
+
+    The solves run in float64 (paper §3), but x64 is on only for the
+    duration of the call: the process keeps its 32-bit default afterwards,
+    so the model it goes on to serve traces with 32-bit indices and floats
+    (Mosaic refuses 64-bit index arithmetic in the kernels' block maps)."""
+    with jax.enable_x64(True):
+        return _quantize(cfg, params, calib_tokens, policy, rotate, patches,
+                         progress, resume_dir)
+
+
+def _quantize(cfg, params, calib_tokens, policy, rotate, patches, progress,
+              resume_dir):
     if rotate:
         params = rotate_model(cfg, params)
     rd = Path(resume_dir) if resume_dir else None

@@ -373,12 +373,10 @@ class ServeEngine:
         if self.mesh is not None:
             # every jitted call runs (and first traces) under the mesh, so
             # shard_map picks up the right ambient mesh at trace time
-            from repro.core.jaxcompat import set_mesh
-
             def _with_mesh(fn, m=self.mesh):
                 @functools.wraps(fn)
                 def call(*args):
-                    with set_mesh(m):
+                    with jax.set_mesh(m):
                         return fn(*args)
                 return call
 
@@ -588,8 +586,10 @@ class ServeEngine:
         """The kernel plan the batched decode step actually runs: QLinear
         flattens (B, 1, K) activations to an (M=B, K) GEMM, so the plan must
         be resolved at M = ``batch_slots``, not the per-slot M=1 the old
-        slot-loop engine implied.  Uses the largest QLinear in the params
-        (the dominant GEMM of the step); None for FP params."""
+        slot-loop engine implied.  The top-level fields describe the
+        largest QLinear (the dominant GEMM of the step); ``shapes`` maps
+        every distinct "KxNrR" layer shape to its own plan.  None for FP
+        params."""
         from repro.kernels.context import gemm_regime
 
         from repro.quant.qlinear import QLinear
@@ -599,20 +599,27 @@ class ServeEngine:
         qls = [l for l in leaves if isinstance(l, QLinear)]
         if not qls:
             return None
+
+        def plan_of(q):
+            ctx = q.ctx
+            if ctx is None:
+                from repro.kernels import ops
+                ctx = ops.default_context()
+            r = 0 if q.u is None else int(q.u.shape[-1])
+            plan = ctx.resolve_plan(self.b, q.d_in, q.d_out, r,
+                                    layer=q.name, act_group=q.act_group)
+            return r, {"impl": q.impl, "path": plan.path, "bm": plan.bm,
+                       "bn": plan.bn, "bk": plan.bk, "br": plan.br,
+                       "variant": plan.variant}
+
+        shapes = {}
+        for q in qls:
+            r, plan = plan_of(q)
+            shapes.setdefault(f"{q.d_in}x{q.d_out}r{r}", plan)
         q = max(qls, key=lambda l: l.d_in * l.d_out)
-        ctx = q.ctx
-        if ctx is None:
-            from repro.kernels import ops
-            ctx = ops.default_context()
-        r = 0 if q.u is None else int(q.u.shape[1])
-        plan = ctx.resolve_plan(self.b, q.d_in, q.d_out, r,
-                                layer=q.name, act_group=q.act_group)
-        return {
-            "m": self.b, "k": q.d_in, "n": q.d_out, "r": r,
-            "regime": gemm_regime(self.b), "impl": q.impl,
-            "path": plan.path, "bm": plan.bm, "bn": plan.bn, "bk": plan.bk,
-            "br": plan.br, "variant": plan.variant,
-        }
+        r, plan = plan_of(q)
+        return {"m": self.b, "k": q.d_in, "n": q.d_out, "r": r,
+                "regime": gemm_regime(self.b), **plan, "shapes": shapes}
 
     # -- admission ----------------------------------------------------------
 

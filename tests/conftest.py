@@ -1,24 +1,8 @@
 import os
-import sys
 
 # Tests run on the single real CPU device; the 512-device production mesh is
 # exercised ONLY by launch/dryrun.py (which sets XLA_FLAGS itself).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# The hermetic container has no `hypothesis`; gate the property tests behind
-# a deterministic stub rather than losing the whole suite to a collect error.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import importlib.util
-    import pathlib
-
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", pathlib.Path(__file__).parent / "_hypothesis_stub.py"
-    )
-    _stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_stub)
-    sys.modules["hypothesis"] = _stub
 
 import numpy as np
 import pytest

@@ -14,6 +14,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from repro.configs import get_config
+from repro.launch.mesh import auto_mesh
 from repro.models.config import reduced
 from repro.models import moe as moe_lib
 import dataclasses
@@ -25,9 +26,8 @@ p = moe_lib.init_moe_params(cfg, key, jnp.float32)
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)), jnp.float32)
 
-from repro.core.jaxcompat import make_mesh, set_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+mesh = auto_mesh((2, 4), ("data", "model"))
+with jax.set_mesh(mesh):
     dense = jax.jit(lambda p, x: moe_lib.moe_block(cfg, p, x, impl="dense"))(p, x)
     ep = jax.jit(lambda p, x: moe_lib.moe_block(cfg, p, x, impl="ep"))(p, x)
 err = float(jnp.abs(dense - ep).max())
@@ -37,7 +37,7 @@ assert rel < 2e-5, rel
 
 # with a tight capacity factor, EP drops tokens but stays finite
 cfg2 = dataclasses.replace(cfg, capacity_factor=0.5)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ep2 = jax.jit(lambda p, x: moe_lib.moe_block(cfg2, p, x, impl="ep"))(p, x)
 assert bool(jnp.all(jnp.isfinite(ep2)))
 print("OK")
@@ -91,13 +91,14 @@ def _ep_problem(n_experts, capacity_factor, t=16, d=8, h=16, k=2, seed=0):
 
 
 def _run_ep(cfg, p, x, weights, top_idx, with_stats):
+    import jax
     import jax.numpy as jnp
 
-    from repro.core.jaxcompat import make_mesh, set_mesh
     from repro.distributed.ep import experts_ep
+    from repro.launch.mesh import auto_mesh
 
-    mesh = make_mesh((1,), ("model",))
-    with set_mesh(mesh):
+    mesh = auto_mesh((1,), ("model",))
+    with jax.set_mesh(mesh):
         return experts_ep(cfg, {"experts": {k_: jnp.asarray(v) for k_, v in
                                             p["experts"].items()}},
                           jnp.asarray(x), jnp.asarray(weights),

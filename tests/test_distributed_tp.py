@@ -1,9 +1,10 @@
 """Tensor-parallel W4A4+LRC under shard_map (distributed/tp.py) on a forced
-8-device host mesh: layer-level numerics contract (column bitwise, row one
-psum + ulp drift), trace/HLO collective counts, shape-keyed kernel-plan
-resolution at the LOCAL shard shape, sharding-preserving retag, and the
-mesh-mode ServeEngine's run-to-run determinism.  Subprocesses, so the
-1-device tests elsewhere keep their platform config."""
+8-device host mesh: layer-level numerics contract (every layer kind within
+a few ulp of the bf16 LR dtype; row one psum), trace/HLO collective counts,
+shape-keyed kernel-plan resolution at the LOCAL shard shape,
+sharding-preserving retag, and the mesh-mode ServeEngine's run-to-run
+determinism.  Subprocesses, so the 1-device tests elsewhere keep their
+platform config."""
 
 import os
 import subprocess
@@ -17,8 +18,8 @@ import numpy as np
 import jax, jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.jaxcompat import make_mesh, set_mesh
 from repro.distributed import tp as tp_lib
+from repro.launch.mesh import auto_mesh
 from repro.models import model as model_lib
 from repro.models.config import reduced
 from repro.quant.calibrate import quantize_model
@@ -33,7 +34,7 @@ calib = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
 q = quantize_model(cfg, params, calib,
                    QuantPolicy(rank_frac=0.10, impl="sim", clip_ratio=0.9,
                                act_group=16))
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 sp, plan = tp_lib.shard_params(q, mesh)
 kinds = {e["path"]: e["parallel"] for e in plan}
 assert kinds["layers/attn/wq"] == "column", kinds
@@ -66,36 +67,45 @@ def get(tree, path):
 
 rng = np.random.default_rng(0)
 
-# column-parallel: BITWISE vs the single-device jitted apply
+
+def lr_dtype_bound(ref, got):
+    # the LRC factors are STORED bf16 and XLA may keep a bf16 intermediate
+    # of (x V) Uᵀ at higher precision in one program and round it in
+    # another (excess precision is on by default), so every TP layer kind
+    # is held to a few ulp of the LR dtype, not to bitwise equality
+    d = float(np.abs(np.asarray(ref) - np.asarray(got)).max())
+    scale = float(np.abs(np.asarray(ref)).max())
+    assert d <= max(1e-6, 4 * 2.0 ** -8 * scale), (d, scale)
+
+
+# column-parallel: no collective, within the LR-dtype bound of the
+# single-device jitted apply
 col = flat(get(sp, "layers/attn/wq"))
 xc = jnp.asarray(rng.standard_normal((8, col.d_in)), jnp.float32)
 ref = jax.jit(lambda x: qlinear_apply(tp_lib._strip(col), x))(xc)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = jax.jit(lambda x: qlinear_apply(col, x))(xc)
-assert np.array_equal(np.asarray(ref), np.asarray(got)), "column not bitwise"
+lr_dtype_bound(ref, got)
 
-# replicate-tagged: also BITWISE (runs the identical full-shape apply)
+# replicate-tagged: the identical full-shape apply in the shard body
 rep = dataclasses.replace(col, parallel="replicate")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = jax.jit(lambda x: qlinear_apply(rep, x))(xc)
-assert np.array_equal(np.asarray(ref), np.asarray(got)), "replicate not bitwise"
+lr_dtype_bound(ref, got)
 
-# row-parallel: ONE f32 psum, output within ~1 ulp of single-device
+# row-parallel: ONE f32 psum
 row = flat(get(sp, "layers/attn/wo"))
 xo = jnp.asarray(rng.standard_normal((8, row.d_in)), jnp.float32)
 ref = jax.jit(lambda x: qlinear_apply(tp_lib._strip(row), x))(xo)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = jax.jit(lambda x: qlinear_apply(row, x))(xo)
-d = float(np.abs(np.asarray(ref) - np.asarray(got)).max())
-scale = float(np.abs(np.asarray(ref)).max())
-# drift bound: the GEMM partial reassociates in f32 (~eps_f32), but the
-# LRC factors are STORED bf16, so K-splitting the x@V contraction re-rounds
-# the bf16 partials — a few ulp of the LR dtype is the honest bound
-assert d <= max(1e-6, 4 * 2.0 ** -8 * scale), (d, scale)
+# drift: the GEMM partial reassociates in f32 (~eps_f32), and K-splitting
+# the x@V contraction re-rounds the bf16 partials
+lr_dtype_bound(ref, got)
 
 # trace-level collective counts: row = exactly ONE psum, zero gathers
 # (the zero-extra-collective invariant: the LRC partial rides the same psum)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     s_row = str(jax.make_jaxpr(lambda x: qlinear_apply(row, x))(xo))
     s_col = str(jax.make_jaxpr(lambda x: qlinear_apply(col, x))(xc))
 assert s_row.count("psum") == 1, s_row.count("psum")
@@ -103,7 +113,7 @@ assert "all_gather" not in s_row
 assert "psum" not in s_col and "all_gather" not in s_col
 
 # compiled HLO of the row layer: exactly one all-reduce
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     hlo = jax.jit(lambda x: qlinear_apply(row, x)).lower(xo).compile().as_text()
 n_ar = sum(1 for ln_ in hlo.splitlines()
            if " all-reduce(" in ln_ or " all-reduce-start(" in ln_)
@@ -145,7 +155,7 @@ import numpy as np
 import jax, jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.jaxcompat import make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.models import model as model_lib
 from repro.models.config import reduced
 from repro.quant.calibrate import quantize_model
@@ -162,7 +172,7 @@ calib = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
 q = quantize_model(cfg, params, calib,
                    QuantPolicy(rank_frac=0.10, impl="sim", clip_ratio=0.9,
                                act_group=16))
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 prompts = [rng.integers(0, cfg.vocab_size, size=6).astype(np.int32)
            for _ in range(3)]
 
@@ -197,7 +207,7 @@ mcalib = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, mcfg.vocab_size)
 mq = quantize_model(mcfg, mparams, mcalib,
                     QuantPolicy(rank_frac=0.10, impl="sim", clip_ratio=0.9,
                                 act_group=16))
-mmesh = make_mesh((1, 2), ("data", "model"))
+mmesh = auto_mesh((1, 2), ("data", "model"))
 
 
 def mrun():

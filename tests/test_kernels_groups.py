@@ -157,9 +157,9 @@ def test_group_equals_k_scale_plane_matches_per_token(rng):
 
 def test_snap_bk_to_group():
     assert snap_bk_to_group(512, 128) == 512   # already a multiple
-    assert snap_bk_to_group(512, 96) == 384    # 96 * 2^2
-    assert snap_bk_to_group(256, 96) == 192    # 96 * 2
-    assert snap_bk_to_group(100, 96) == 96     # floor: one group
+    assert snap_bk_to_group(512, 96) == 384    # lcm(96, 128)
+    assert snap_bk_to_group(256, 96) == 384    # lane chunks: up to the lcm
+    assert snap_bk_to_group(100, 96) == 96     # whole-K chunk: one group
     assert snap_bk_to_group(64, 128) == 128    # g > bk snaps UP to g
     assert snap_bk_to_group(4096, 4096) == 4096  # g = K pins bk = K
 

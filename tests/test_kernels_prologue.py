@@ -160,9 +160,10 @@ def test_select_blocks_regimes():
     assert p.bm <= 16 and p.bn >= 128 and p.br <= 128
     assert ops.select_blocks(256, 4096, 11008, 128).bm == 128   # mixed
     assert ops.select_blocks(2048, 4096, 11008, 128).bm == 256  # prefill
-    # tiny problems clamp every block below the table entry
+    # tiny problems clamp every block below the table entry; N and R tiles
+    # stop at one lane width (the TPU's block rule) and pad the extent up
     p4 = ops.select_blocks(8, 64, 32, 0)
-    assert p4.bm <= 8 and p4.bn <= 32 and p4.bk <= 64 and p4.br <= 8
+    assert p4.bm <= 8 and p4.bn == 128 and p4.bk <= 64 and p4.br == 128
 
 
 def test_qlinear_pallas_impl_matches_int8_odd_shapes(rng):

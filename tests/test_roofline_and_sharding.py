@@ -98,9 +98,8 @@ def test_config_registry_complete():
 
 def _mesh22():
     # AbstractMesh: rule logic only needs axis names/sizes (1-device CPU test)
-    from repro.core.jaxcompat import abstract_mesh
-
-    return abstract_mesh((2, 2), ("data", "model"))
+    return jax.sharding.AbstractMesh((2, 2), ("data", "model"),
+                                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_param_rules_shard_attention_and_mlp():

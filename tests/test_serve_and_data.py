@@ -106,10 +106,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import auto_mesh
 from repro.train.compression import compressed_psum, zero_residual
 
-from repro.core.jaxcompat import make_mesh, set_mesh, shard_map
-mesh = make_mesh((4,), ("data",))
+mesh = auto_mesh((4,), ("data",))
 rng = np.random.default_rng(0)
 g_local = jnp.asarray(rng.standard_normal((4, 64, 32)), jnp.float32)
 
@@ -119,9 +119,10 @@ def f(g):
         res = zero_residual(grads)
         out, _ = compressed_psum(grads, res, "data")
         return out["w"]
-    return shard_map(inner, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(g)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P("data"),
+                         out_specs=P("data"))(g)
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out = jax.jit(f)(g_local)
 exact = jnp.mean(g_local, axis=0, keepdims=True)
 err = float(jnp.abs(out[0] - exact[0]).max()) / float(jnp.abs(exact).max())
