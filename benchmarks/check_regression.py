@@ -14,9 +14,8 @@ the CURRENT code and compares them against the committed baseline
 
 With ``--serve`` the gate instead compares a freshly measured serving run
 (``results/BENCH_serve_smoke.json`` from ``benchmarks.serve_latency
---smoke``) against the committed ``results/BENCH_serve.json``.  Wall-clock
-columns are informational (CI runners are too noisy); the gate guards the
-DETERMINISTIC efficiency columns — ``decode_calls_per_token`` (must stay
+--smoke``) against the committed ``results/BENCH_serve.json``.  The gate
+guards its DETERMINISTIC efficiency columns — ``decode_calls_per_token`` (must stay
 exactly ``1/batch``: one batched decode call per engine step),
 ``prefill_chunks_per_prompt`` and ``kv_bytes_per_token`` (the quantized-KV
 footprint per cached token; growth means the paged pools or scale planes

@@ -3,20 +3,17 @@ as a function of batch size and page size.
 
 Emits ``results/BENCH_serve.json`` (``results/BENCH_serve_smoke.json`` with
 ``--smoke``) in the shared ``benchmarks.common.record`` layout; the column
-schema is documented in docs/serving.md.  Two kinds of columns:
-
-* **wall-clock** (``prefill_ms_per_token``, ``decode_ms_per_token``) —
-  informational.  CPU-interpret wall time is noisy across runners, so the
-  CI gate does NOT fail on it.
-* **deterministic efficiency** (``decode_calls_per_token``,
-  ``prefill_chunks_per_prompt``) — these are exact consequences of the
-  engine's batching structure: one batched decode call per engine step
-  makes ``decode_calls_per_token == 1/batch`` whatever the token count, and
-  chunked prefill issues exactly ``ceil(prompt_len/chunk)`` forwards per
-  prompt.  The CI regression gate (``benchmarks.check_regression --serve``)
-  fails if either grows — i.e. if batching quietly degenerates back toward
-  per-slot decode calls.  Both are token-count invariant, so the --smoke
-  rows (fewer new tokens) gate against the committed full baseline.
+schema is documented in docs/serving.md.  Its columns are counts, not
+times: ``decode_calls_per_token`` and ``prefill_chunks_per_prompt`` are
+exact consequences of the engine's batching structure: one batched decode
+call per engine step makes ``decode_calls_per_token == 1/batch`` whatever
+the token count, and chunked prefill issues exactly
+``ceil(prompt_len/chunk)`` forwards per prompt.  The CI regression gate
+(``benchmarks.check_regression --serve``) fails if either grows — i.e. if
+batching quietly degenerates back toward per-slot decode calls.  Both are
+token-count invariant, so the --smoke rows (fewer new tokens) gate against
+the committed full baseline.  Serving times come from the chip benchmark
+(``bench/``) and the engine's ``serve.*`` trace spans.
 
 Run on the reduced smollm config with synthetic FP weights: serving-path
 latency structure (calls per token, chunk interleaving, page bookkeeping)
@@ -28,7 +25,6 @@ does not depend on the weight values, and FP keeps CI runtime flat.
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import numpy as np
@@ -37,21 +33,20 @@ from benchmarks.common import record
 from repro.configs import get_config
 from repro.models import model as model_lib
 from repro.models.config import reduced
-from repro.serve.engine import Request, RequestState, ServeEngine
+from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvquant import KVSpec
 
 HEADER = [
     "batch", "page_size", "prefill_chunk", "kv_dtype", "requests",
     "prompt_len", "new_tokens",
-    "prefill_ms_per_token", "decode_ms_per_token",
     "decode_calls", "decode_calls_per_token", "prefill_chunks_per_prompt",
     "paged_traces", "kv_bytes_per_token",
 ]
 
 PROMPT_LEN = 24
 MAX_SEQ = 64
-# (batch, page_size, prefill_chunk, kv_dtype) — the acceptance grid: decode
-# ms/token at B in {1, 4, 16}, a page-size point, a chunked-prefill point,
+# (batch, page_size, prefill_chunk, kv_dtype) — the acceptance grid: B in
+# {1, 4, 16}, a page-size point, a chunked-prefill point,
 # and the quantized-KV points (int8 per-head, int4 per-head) whose
 # kv_bytes_per_token column the regression gate holds at the >=3x / >=5x
 # reductions the paged pools deliver
@@ -69,33 +64,22 @@ def _mk_engine(cfg, params, batch, page_size, chunk, kv_dtype):
 
 
 def _drive(cfg, params, batch, page_size, chunk, kv_dtype, new_tokens):
-    """One wave of ``batch`` identical-length requests; returns timings and
-    the engine for counter inspection."""
+    """One wave of ``batch`` identical-length requests; returns the engine
+    for counter inspection."""
     rng = np.random.default_rng(0)
     prompts = [np.asarray(rng.integers(0, cfg.vocab_size, (PROMPT_LEN,)),
                           np.int32) for _ in range(batch)]
     eng = _mk_engine(cfg, params, batch, page_size, chunk, kv_dtype)
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=new_tokens))
-
-    t0 = time.perf_counter()
-    eng._admit()
-    while any(r is not None and r.state is RequestState.PREFILLING
-              for r in eng.slot_req):
-        eng._prefill_tick()
-    t_prefill = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
     done = eng.run()
-    t_decode = time.perf_counter() - t0
-
     assert all(done[i].ok for i in range(batch)), \
         {i: (done[i].status, done[i].error) for i in done}
     assert all(len(done[i].out_tokens) == new_tokens for i in range(batch))
     # pages all came back on the terminal transitions
     assert eng.alloc.free_pages == eng.alloc.capacity
     eng.alloc.check()
-    return eng, t_prefill, t_decode
+    return eng
 
 
 def bench_case(cfg, params, batch, page_size, chunk, kv_dtype, new_tokens):
@@ -103,15 +87,14 @@ def bench_case(cfg, params, batch, page_size, chunk, kv_dtype, new_tokens):
     # run twice: the first run compiles (the jitted fns are shared
     # process-wide per config, so the second run is pure execution)
     for it in range(2):
-        eng, t_prefill, t_decode = _drive(cfg, params, batch, page_size,
-                                          chunk, kv_dtype, new_tokens)
+        eng = _drive(cfg, params, batch, page_size, chunk, kv_dtype,
+                     new_tokens)
         if it == 0:
             fns_traces = dict(eng.health()["traces"])
     # retracing on the measured run would mean the engine's shapes are not
     # stable step-to-step — that is a bug, not a measurement artifact
     assert eng.health()["traces"] == fns_traces, "decode retraced while serving"
 
-    prefill_tokens = batch * PROMPT_LEN
     decode_tokens = batch * (new_tokens - 1)  # first token comes from prefill
     decode_calls = eng.counters["decode_calls"]
     assert decode_calls == new_tokens - 1, (decode_calls, new_tokens)
@@ -119,8 +102,6 @@ def bench_case(cfg, params, batch, page_size, chunk, kv_dtype, new_tokens):
     return [
         batch, page_size, 0 if chunk is None else chunk, kv_dtype, batch,
         PROMPT_LEN, new_tokens,
-        round(t_prefill * 1e3 / prefill_tokens, 4),
-        round(t_decode * 1e3 / decode_tokens, 4),
         decode_calls,
         round(decode_calls / decode_tokens, 6),
         chunks,

@@ -59,6 +59,7 @@ def act_quant_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # M tiles are independent
         ),
+        name="act_quant_kernel",
         interpret=interpret,
     )(x)
     return q, s

@@ -232,6 +232,7 @@ def paged_flash_attention_kernel(
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), q.dtype),
+        name="paged_flash_attention_kernel",
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       qg, kp, vp)
@@ -263,6 +264,7 @@ def flash_attention_kernel(
         ],
         out_specs=pl.BlockSpec((1, bq, v.shape[-1]), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, v.shape[-1]), q.dtype),
+        name="flash_attention_kernel",
         interpret=interpret,
     )(q, k, v)
 
@@ -305,6 +307,7 @@ def flash_attention_quant_kernel(
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        name="flash_attention_quant_kernel",
         interpret=interpret,
     )(q, k_quant, k_scales, v_quant, v_scales)
 
@@ -355,6 +358,7 @@ def paged_flash_attention_quant_kernel(
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
+        name="paged_flash_attention_quant_kernel",
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       qg, kp, ksp, vp, vsp)

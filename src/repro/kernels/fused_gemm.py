@@ -348,5 +348,6 @@ def fused_w4a4_lrc_kernel(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
         ),
+        name="fused_w4a4_lrc_kernel",
         interpret=interpret,
     )(*operands)

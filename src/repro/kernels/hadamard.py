@@ -38,5 +38,6 @@ def fwht_kernel(x: jnp.ndarray, bm: int = 256, *, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # M tiles are independent
         ),
+        name="fwht_kernel",
         interpret=interpret,
     )(x)

@@ -140,6 +140,7 @@ def fused_prologue_kernel(
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
+            name="fused_prologue_kernel",
             interpret=interpret,
         )(x)
         return q, s, None
@@ -194,6 +195,7 @@ def fused_prologue_kernel(
         # the xv block residency and must stay sequential.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="fused_prologue_kernel",
         interpret=interpret,
     )(x, vp)
     return q[:, :k], s[:, :n_s], xv[:, :r]

@@ -149,6 +149,7 @@ def w4a4_lowrank_matmul_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="w4a4_lowrank_matmul_kernel",
         interpret=interpret,
     )(*operands)
     return out

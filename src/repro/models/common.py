@@ -14,11 +14,12 @@ from repro.quant.qlinear import apply_linear
 
 
 def rms_norm(x: jnp.ndarray, gamma: jnp.ndarray, eps: float) -> jnp.ndarray:
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * gamma.astype(jnp.float32)).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        x = x * jax.lax.rsqrt(var + eps)
+        return (x * gamma.astype(jnp.float32)).astype(dt)
 
 
 def layer_norm(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -200,13 +201,14 @@ def sharded_attention(q, k, v, mask, scale: float):
 
 def mlp_block(p: dict, x: jnp.ndarray, act: str) -> jnp.ndarray:
     """Gated MLP: SwiGLU (silu) or GeGLU (gelu)."""
-    g = apply_linear(p["wg"], x)
-    u = apply_linear(p["wu"], x)
-    if act == "silu":
-        h = jax.nn.silu(g) * u
-    else:
-        h = jax.nn.gelu(g, approximate=True) * u
-    return apply_linear(p["wd"], h)
+    with jax.named_scope("mlp"):
+        g = apply_linear(p["wg"], x)
+        u = apply_linear(p["wu"], x)
+        if act == "silu":
+            h = jax.nn.silu(g) * u
+        else:
+            h = jax.nn.gelu(g, approximate=True) * u
+        return apply_linear(p["wd"], h)
 
 
 def paged_cache_update(
@@ -291,11 +293,13 @@ def paged_gqa_attention_block(
     if cfg.rope_theta > 0:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    pages_k = paged_cache_update(pages_k, k, block_table, positions, valid)
-    pages_v = paged_cache_update(pages_v, v, block_table, positions, valid)
-    kc = pages_k[block_table].reshape(b, -1, kh, hd).astype(x.dtype)
-    vc = pages_v[block_table].reshape(b, -1, kh, hd).astype(x.dtype)
-    out = sharded_attention(q, kc, vc, mask, scale=1.0 / (hd**0.5))
+    with jax.named_scope("kv_write"):
+        pages_k = paged_cache_update(pages_k, k, block_table, positions, valid)
+        pages_v = paged_cache_update(pages_v, v, block_table, positions, valid)
+    with jax.named_scope("attention"):
+        kc = pages_k[block_table].reshape(b, -1, kh, hd).astype(x.dtype)
+        vc = pages_v[block_table].reshape(b, -1, kh, hd).astype(x.dtype)
+        out = sharded_attention(q, kc, vc, mask, scale=1.0 / (hd**0.5))
     out = apply_linear(p["wo"], out.reshape(b, s, h * hd))
     return out, pages_k, pages_v
 
@@ -335,19 +339,21 @@ def paged_gqa_attention_block_quantized(
     if cfg.rope_theta > 0:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    pages_k, scales_k = paged_cache_update_quantized(
-        pages_k, scales_k, k, block_table, positions, valid, kv_spec)
-    pages_v, scales_v = paged_cache_update_quantized(
-        pages_v, scales_v, v, block_table, positions, valid, kv_spec)
+    with jax.named_scope("kv_write"):
+        pages_k, scales_k = paged_cache_update_quantized(
+            pages_k, scales_k, k, block_table, positions, valid, kv_spec)
+        pages_v, scales_v = paged_cache_update_quantized(
+            pages_v, scales_v, v, block_table, positions, valid, kv_spec)
     phd = kv_spec.packed_head_dim(hd)
     n_g = kv_spec.n_groups(hd)
-    kc = dequantize_kv(pages_k[block_table].reshape(b, -1, kh, phd),
-                       scales_k[block_table].reshape(b, -1, kh, n_g),
-                       kv_spec, hd).astype(x.dtype)
-    vc = dequantize_kv(pages_v[block_table].reshape(b, -1, kh, phd),
-                       scales_v[block_table].reshape(b, -1, kh, n_g),
-                       kv_spec, hd).astype(x.dtype)
-    out = sharded_attention(q, kc, vc, mask, scale=1.0 / (hd**0.5))
+    with jax.named_scope("attention"):
+        kc = dequantize_kv(pages_k[block_table].reshape(b, -1, kh, phd),
+                           scales_k[block_table].reshape(b, -1, kh, n_g),
+                           kv_spec, hd).astype(x.dtype)
+        vc = dequantize_kv(pages_v[block_table].reshape(b, -1, kh, phd),
+                           scales_v[block_table].reshape(b, -1, kh, n_g),
+                           kv_spec, hd).astype(x.dtype)
+        out = sharded_attention(q, kc, vc, mask, scale=1.0 / (hd**0.5))
     out = apply_linear(p["wo"], out.reshape(b, s, h * hd))
     return out, pages_k, pages_v, scales_k, scales_v
 

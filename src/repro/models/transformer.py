@@ -108,14 +108,15 @@ def embed_tokens(cfg, params, tokens):
 def unembed(cfg, params, x):
     # rotation fusion (QuaRot) may materialize an explicit lm_head for tied
     # models (final-norm γ cannot be folded into a shared embedding)
-    if "lm_head" in params:
-        head = params["lm_head"]
-    else:
-        head = params["embed"].T
-    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-    if cfg.logit_softcap > 0:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits
+    with jax.named_scope("unembed"):
+        if "lm_head" in params:
+            head = params["lm_head"]
+        else:
+            head = params["embed"].T
+        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+        if cfg.logit_softcap > 0:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        return logits
 
 
 def forward(cfg, params, tokens, prefix_len: int = 0, embeds=None):

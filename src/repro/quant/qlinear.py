@@ -212,9 +212,12 @@ def qlinear_apply(q: QLinear, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def apply_linear(w, x: jnp.ndarray) -> jnp.ndarray:
-    """Dispatch: plain array → dense matmul; QLinear → W4A4+LRC path."""
+    """Dispatch: plain array → dense matmul; QLinear → W4A4+LRC path, under
+    the ``qlinear`` named scope (the device trace's ops of the kernel, its
+    prologue and the pads and converts around it carry it)."""
     if isinstance(w, QLinear):
-        return qlinear_apply(w, x)
+        with jax.named_scope("qlinear"):
+            return qlinear_apply(w, x)
     return x @ w.astype(x.dtype)
 
 

@@ -108,6 +108,13 @@ from repro.serve.lifecycle import (ErrorKind, Request, RequestRecord,
 from repro.serve.paging import PageAllocator
 from repro.serve.sampling import NonFiniteLogitsError, sample_token
 
+# Host spans of the serving phases (``serve.*``): they land in the
+# profiler's own trace, on the device ops' clock, whenever a profiler session
+# runs, and cost about a microsecond each when none does, so they are always
+# opened.  Their keyword arguments and ``set_metadata`` values (``rid``,
+# ``step``, ``rows``, ...) become stats of the trace event.
+span = jax.profiler.TraceAnnotation
+
 
 class PagesExhausted(RuntimeError):
     """The free list could not cover a page allocation (admission raced, or
@@ -664,7 +671,14 @@ class ServeEngine:
         return None
 
     def _admit(self) -> bool:
-        progressed = False
+        with span("serve.admit") as sp:
+            admitted = self._admit_queued()
+            sp.set_metadata(admitted=admitted)
+        return admitted > 0
+
+    def _admit_queued(self) -> int:
+        """Fill free slots from the queue; returns how many were admitted."""
+        admitted = 0
         for i in range(self.b):
             # a slot that finishes/fails at prefill frees up immediately,
             # so keep pulling from the queue until it sticks or the queue
@@ -682,11 +696,11 @@ class ServeEngine:
                         # FIFO order until co-tenants free enough pages
                         # (all-idle implies all pages free, so this cannot
                         # deadlock for a prompt that passed _validate)
-                        return progressed
+                        return admitted
                 req = self.queue.pop(0)
-                progressed = True
+                admitted += 1
                 self._admit_one(i, req)
-        return progressed
+        return admitted
 
     def _admit_one(self, i: int, req: Request):
         req.advance(RequestState.PREFILLING, self.clock())
@@ -706,14 +720,18 @@ class ServeEngine:
         """Advance every mid-prefill slot by one chunk (paged mode), or
         retry a whole-prompt prefill whose last attempt failed."""
         progressed = False
-        for i in range(self.b):
-            req = self.slot_req[i]
-            if req is None or req.state is not RequestState.PREFILLING:
-                continue
-            if self.mode == "paged":
-                progressed |= self._prefill_advance(i)
-            else:
-                progressed |= self._slot_prefill(i, req)
+        with span("serve.prefill") as sp:
+            chunks = 0
+            for i in range(self.b):
+                req = self.slot_req[i]
+                if req is None or req.state is not RequestState.PREFILLING:
+                    continue
+                chunks += 1
+                if self.mode == "paged":
+                    progressed |= self._prefill_advance(i)
+                else:
+                    progressed |= self._slot_prefill(i, req)
+            sp.set_metadata(chunks=chunks)
         return progressed
 
     # -- prefill ------------------------------------------------------------
@@ -724,6 +742,12 @@ class ServeEngine:
         and length are untouched, so the retry replays the same chunk from
         clean state."""
         req = self.slot_req[i]
+        with span("serve.prefill.chunk", rid=req.rid) as sp:
+            return self._prefill_chunk(i, req, sp)
+
+    def _prefill_chunk(self, i: int, req: Request, sp) -> bool:
+        """The body of ``_prefill_advance``; ``sp`` is the attempt's
+        ``serve.prefill.chunk`` span, which gets the chunk's token count."""
         prompt = np.asarray(req.prompt, np.int32)
         if req.out_tokens:
             # recovery resume: requests restored mid-stream re-prefill over
@@ -747,6 +771,7 @@ class ServeEngine:
         off = self._prefill_off[i]
         chunk = self.prefill_chunk or n_prompt
         n = min(chunk, n_prompt - off)
+        sp.set_metadata(tokens=n)
         final = off + n >= n_prompt
         fault = (self.injector.poll(req.rid, "prefill")
                  if self.injector is not None else None)
@@ -774,6 +799,10 @@ class ServeEngine:
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(valid), pool_in,
                 jnp.asarray(self.block_tables[i:i + 1]), jnp.asarray(srow))
+            with span("serve.prefill.wait"):
+                # the host's one wait on the chunk: sampling and the finite
+                # check below read ready logits
+                jax.block_until_ready(logits)
             if fault is not None and fault.kind in ("nan_logits", "inf_logits"):
                 logits = self.injector.corrupt_logits(logits, fault.kind)
             if final:
@@ -880,6 +909,13 @@ class ServeEngine:
                   and self.slot_req[i].state is RequestState.DECODING]
         if not active:
             return False
+        with span("serve.decode", step=self.counters["steps"]) as sp:
+            return self._batched_step(active, sp)
+
+    def _batched_step(self, active: List[int], sp) -> bool:
+        """One batched decode call over the ``active`` slots (paged and
+        stacked modes), its per-row sampling and its commit.  ``sp`` is the
+        step's ``serve.decode`` span; it gets the call's row count."""
         progressed = False
         faults: Dict[int, object] = {}
         if self.injector is not None:
@@ -893,37 +929,48 @@ class ServeEngine:
                         raise SimulatedCrash(
                             f"simulated crash at decode of rid "
                             f"{self.slot_req[i].rid}")
-        if self.mode == "paged":
-            # decode-boundary crossings allocate before the forward; a dry
-            # free list fails ONLY that slot's attempt (deferred retry —
-            # a co-tenant may free pages by the next step)
-            for i in list(active):
-                req = self.slot_req[i]
-                got = self.alloc.ensure(req.rid, int(self.lengths[i]) + 1)
-                if got is None:
-                    active.remove(i)
-                    self._attempt_failed(i, req, PagesExhausted(
-                        f"no free page for rid {req.rid} at position "
-                        f"{int(self.lengths[i])} ({self.alloc.free_pages} "
-                        f"free of {self.alloc.capacity})"))
-                    progressed = True
-                elif got:
-                    self._write_block_row(i, req.rid)
-            if not active:
-                return progressed
+        with span("serve.decode.prepare"):
+            if self.mode == "paged":
+                # decode-boundary crossings allocate before the forward; a
+                # dry free list fails ONLY that slot's attempt (deferred
+                # retry — a co-tenant may free pages by the next step)
+                for i in list(active):
+                    req = self.slot_req[i]
+                    got = self.alloc.ensure(req.rid,
+                                            int(self.lengths[i]) + 1)
+                    if got is None:
+                        active.remove(i)
+                        self._attempt_failed(i, req, PagesExhausted(
+                            f"no free page for rid {req.rid} at position "
+                            f"{int(self.lengths[i])} "
+                            f"({self.alloc.free_pages} free of "
+                            f"{self.alloc.capacity})"))
+                        progressed = True
+                    elif got:
+                        self._write_block_row(i, req.rid)
+                if not active:
+                    return progressed
 
-        # injected exceptions fire "before the forward": the slot drops out
-        # of the valid mask (paged) / gets its row rolled back (stacked),
-        # so the ONE batched call still runs for everyone else
-        excluded = {i for i in active
-                    if i in faults and faults[i].kind == "exception"}
-        included = [i for i in active if i not in excluded]
-        corrupt = [i for i in included
-                   if i in faults and faults[i].kind == "cache_corruption"]
+            # injected exceptions fire "before the forward": the slot drops
+            # out of the valid mask (paged) / gets its row rolled back
+            # (stacked), so the ONE batched call still runs for everyone
+            # else
+            excluded = {i for i in active
+                        if i in faults and faults[i].kind == "exception"}
+            included = [i for i in active if i not in excluded]
+            corrupt = [i for i in included
+                       if i in faults and faults[i].kind == "cache_corruption"]
+            sp.set_metadata(rows=len(included))
 
-        tokens = np.zeros((self.b, 1), np.int32)
-        for i in active:
-            tokens[i, 0] = self.slot_req[i].out_tokens[-1]
+            tokens = np.zeros((self.b, 1), np.int32)
+            for i in active:
+                tokens[i, 0] = self.slot_req[i].out_tokens[-1]
+            if self.mode == "paged":
+                valid = np.zeros((self.b, 1), bool)
+                for i in included:
+                    valid[i, 0] = True
+                positions = self.lengths.astype(np.int32)[:, None]
+                srow = np.zeros((self.b,), np.int32)
 
         self.counters["decode_calls"] += 1
         try:
@@ -932,11 +979,6 @@ class ServeEngine:
                 for i in corrupt:
                     pool_in = self.injector.corrupt_pages(
                         pool_in, self.alloc.pages_of(self.slot_req[i].rid))
-                valid = np.zeros((self.b, 1), bool)
-                for i in included:
-                    valid[i, 0] = True
-                positions = self.lengths.astype(np.int32)[:, None]
-                srow = np.zeros((self.b,), np.int32)
                 logits, new_state = self._paged(
                     self.params, jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(valid), pool_in,
@@ -947,6 +989,10 @@ class ServeEngine:
                     cache_in = self.injector.corrupt_rows(cache_in, i)
                 logits, new_state = self._decode(
                     self.params, jnp.asarray(tokens), cache_in)
+            with span("serve.decode.wait"):
+                # the host's one wait on the step: the per-row sampling
+                # below reads ready logits
+                jax.block_until_ready(logits)
         except Exception as e:
             # the one batched call itself died: no slot committed anything,
             # every active request gets a (retryable) failed attempt
@@ -958,74 +1004,85 @@ class ServeEngine:
         # commit+rollback, THEN the bookkeeping — _slot_failure frees pages,
         # which must not happen before the rollback reads them
         outcomes: Dict[int, Tuple[str, object]] = {}
-        for i in active:
-            req = self.slot_req[i]
-            f = faults.get(i)
-            if i in excluded:
-                outcomes[i] = ("fail", InjectedFault(
-                    f"injected decode exception for rid {req.rid}"))
-                continue
-            row = logits[i:i + 1, -1]
-            try:
-                if f is not None and f.kind in ("nan_logits", "inf_logits"):
-                    row = self.injector.corrupt_logits(row, f.kind)
-                sfault = (self.injector.poll(req.rid, "sampling")
-                          if self.injector is not None else None)
-                if sfault is not None:
-                    if sfault.kind == "slow_step":
-                        self.injector.sleep(sfault.seconds)
-                    elif sfault.kind == "process_crash":
-                        # BaseException: escapes this per-request guard AND
-                        # the step — nothing below commits
-                        raise SimulatedCrash(
-                            f"simulated crash at sampling of rid {req.rid}")
-                    elif sfault.kind == "exception":
-                        raise InjectedFault(
-                            f"injected sampling exception for rid {req.rid}")
-                outcomes[i] = ("ok", int(self._sample(req, row)[0]))
-            except Exception as e:  # isolated: fails only this request
-                outcomes[i] = ("fail", e)
+        with span("serve.sample") as ssp:
+            for i in active:
+                req = self.slot_req[i]
+                f = faults.get(i)
+                if i in excluded:
+                    outcomes[i] = ("fail", InjectedFault(
+                        f"injected decode exception for rid {req.rid}"))
+                    continue
+                row = logits[i:i + 1, -1]
+                try:
+                    if f is not None and f.kind in ("nan_logits",
+                                                    "inf_logits"):
+                        row = self.injector.corrupt_logits(row, f.kind)
+                    sfault = (self.injector.poll(req.rid, "sampling")
+                              if self.injector is not None else None)
+                    if sfault is not None:
+                        if sfault.kind == "slow_step":
+                            self.injector.sleep(sfault.seconds)
+                        elif sfault.kind == "process_crash":
+                            # BaseException: escapes this per-request guard
+                            # AND the step — nothing below commits
+                            raise SimulatedCrash(
+                                f"simulated crash at sampling of rid "
+                                f"{req.rid}")
+                        elif sfault.kind == "exception":
+                            raise InjectedFault(
+                                f"injected sampling exception for rid "
+                                f"{req.rid}")
+                    outcomes[i] = ("ok", int(self._sample(req, row)[0]))
+                except Exception as e:  # isolated: fails only this request
+                    outcomes[i] = ("fail", e)
+            ssp.set_metadata(rows=len(included))
 
-        failed = [i for i in active if outcomes[i][0] == "fail"]
-        if self.mode == "paged":
-            # a failed attempt commits nothing: corrupted slots get their
-            # pages restored from the pre-step pool (page-disjointness makes
-            # the restore exact); excluded slots were never written (valid
-            # mask → null page); other failures keep their length, so the
-            # retry overwrites the same position
-            rollback = sorted({p for i in failed if i in corrupt
-                               for p in self.alloc.pages_of(self.slot_req[i].rid)})
-            if rollback:
-                ids = jnp.asarray(rollback, jnp.int32)
-                new_state = jax.tree.map(
-                    lambda new, old: new.at[:, ids].set(old[:, ids]),
-                    new_state, self.pool)
-            self.pool = new_state
-        else:
-            # stacked rows all advance in the batched call — roll back every
-            # failed slot's row to the pre-step cache
-            if failed:
-                ids = jnp.asarray(failed, jnp.int32)
-                new_state = jax.tree.map(
-                    lambda new, old: new.at[:, ids].set(old[:, ids]),
-                    new_state, self.stacked_cache)
-            self.stacked_cache = new_state
-
-        for i in active:
-            req = self.slot_req[i]
-            kind, val = outcomes[i]
-            progressed = True  # a token OR a terminal/retry record is progress
-            if kind == "fail":
-                self._attempt_failed(i, req, val)
-                continue
-            self._attempt_streak.pop(req.rid, None)
-            self.slot_fail_streak[i] = 0
-            self._commit_token(req, val)
+        with span("serve.commit") as csp:
+            failed = [i for i in active if outcomes[i][0] == "fail"]
             if self.mode == "paged":
-                self.lengths[i] += 1
-            if self._should_finish(req, val):
-                self._release_slot(i)
-                self._finalize(req, RequestState.FINISHED)
+                # a failed attempt commits nothing: corrupted slots get their
+                # pages restored from the pre-step pool (page-disjointness
+                # makes the restore exact); excluded slots were never
+                # written (valid mask → null page); other failures keep
+                # their length, so the retry overwrites the same position
+                rollback = sorted({
+                    p for i in failed if i in corrupt
+                    for p in self.alloc.pages_of(self.slot_req[i].rid)})
+                if rollback:
+                    ids = jnp.asarray(rollback, jnp.int32)
+                    new_state = jax.tree.map(
+                        lambda new, old: new.at[:, ids].set(old[:, ids]),
+                        new_state, self.pool)
+                self.pool = new_state
+            else:
+                # stacked rows all advance in the batched call — roll back
+                # every failed slot's row to the pre-step cache
+                if failed:
+                    ids = jnp.asarray(failed, jnp.int32)
+                    new_state = jax.tree.map(
+                        lambda new, old: new.at[:, ids].set(old[:, ids]),
+                        new_state, self.stacked_cache)
+                self.stacked_cache = new_state
+
+            committed = 0
+            for i in active:
+                req = self.slot_req[i]
+                kind, val = outcomes[i]
+                # a token OR a terminal/retry record is progress
+                progressed = True
+                if kind == "fail":
+                    self._attempt_failed(i, req, val)
+                    continue
+                self._attempt_streak.pop(req.rid, None)
+                self.slot_fail_streak[i] = 0
+                self._commit_token(req, val)
+                committed += 1
+                if self.mode == "paged":
+                    self.lengths[i] += 1
+                if self._should_finish(req, val):
+                    self._release_slot(i)
+                    self._finalize(req, RequestState.FINISHED)
+            csp.set_metadata(tokens=committed)
         return progressed
 
     def _step_slots(self) -> bool:
