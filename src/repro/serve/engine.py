@@ -106,7 +106,8 @@ from repro.serve.journal import (JournalError, JournalWriter, collate,
 from repro.serve.lifecycle import (ErrorKind, Request, RequestRecord,
                                    RequestState, TERMINAL_STATES)
 from repro.serve.paging import PageAllocator
-from repro.serve.sampling import NonFiniteLogitsError, sample_token
+from repro.serve.sampling import (NonFiniteLogitsError, count_non_finite,
+                                  non_finite_error, sample_rows_packed)
 
 # Host spans of the serving phases (``serve.*``): they land in the
 # profiler's own trace, on the device ops' clock, whenever a profiler session
@@ -364,6 +365,7 @@ class ServeEngine:
             "submitted": 0, "admitted": 0, "steps": 0, "retries": 0,
             "finished": 0, "failed": 0, "rejected": 0, "cancelled": 0,
             "timed_out": 0, "slot_failures": 0, "decode_calls": 0,
+            "sample_calls": 0,
         }
         # rid -> consecutive failed attempts; a failed attempt retries on
         # the NEXT engine step (deferred retry) so the batched step stays
@@ -817,7 +819,7 @@ class ServeEngine:
                     elif sfault.kind == "exception":
                         raise InjectedFault(
                             f"injected sampling exception for rid {req.rid}")
-                tok = int(self._sample(req, logits[:, -1])[0])
+                tok = int(self._sample(req, logits)[0])
             else:
                 # non-final chunks never sample, but NaN must not reach the
                 # committed pool — LQER-style blow-ups surface here, not
@@ -873,7 +875,7 @@ class ServeEngine:
                 elif sfault.kind == "exception":
                     raise InjectedFault(
                         f"injected sampling exception for rid {req.rid}")
-            tok = int(self._sample(req, logits[:, -1])[0])
+            tok = int(self._sample(req, logits)[0])
         except Exception as e:  # isolated: fails only this request
             self._attempt_failed(i, req, e)
             return True
@@ -914,7 +916,7 @@ class ServeEngine:
 
     def _batched_step(self, active: List[int], sp) -> bool:
         """One batched decode call over the ``active`` slots (paged and
-        stacked modes), its per-row sampling and its commit.  ``sp`` is the
+        stacked modes), its one sampling call and its commit.  ``sp`` is the
         step's ``serve.decode`` span; it gets the call's row count."""
         progressed = False
         faults: Dict[int, object] = {}
@@ -990,8 +992,8 @@ class ServeEngine:
                 logits, new_state = self._decode(
                     self.params, jnp.asarray(tokens), cache_in)
             with span("serve.decode.wait"):
-                # the host's one wait on the step: the per-row sampling
-                # below reads ready logits
+                # the host's one wait on the step: the sampling below
+                # reads ready logits
                 jax.block_until_ready(logits)
         except Exception as e:
             # the one batched call itself died: no slot committed anything,
@@ -1005,6 +1007,7 @@ class ServeEngine:
         # which must not happen before the rollback reads them
         outcomes: Dict[int, Tuple[str, object]] = {}
         with span("serve.sample") as ssp:
+            sampled: List[Optional[Request]] = [None] * self.b
             for i in active:
                 req = self.slot_req[i]
                 f = faults.get(i)
@@ -1012,29 +1015,40 @@ class ServeEngine:
                     outcomes[i] = ("fail", InjectedFault(
                         f"injected decode exception for rid {req.rid}"))
                     continue
-                row = logits[i:i + 1, -1]
+                if f is not None and f.kind in ("nan_logits", "inf_logits"):
+                    logits = logits.at[i].set(
+                        self.injector.corrupt_logits(logits[i], f.kind))
+                sfault = (self.injector.poll(req.rid, "sampling")
+                          if self.injector is not None else None)
+                if sfault is not None:
+                    if sfault.kind == "slow_step":
+                        self.injector.sleep(sfault.seconds)
+                    elif sfault.kind == "process_crash":
+                        # BaseException: escapes the step — nothing below
+                        # commits
+                        raise SimulatedCrash(
+                            f"simulated crash at sampling of rid {req.rid}")
+                    elif sfault.kind == "exception":
+                        outcomes[i] = ("fail", InjectedFault(
+                            f"injected sampling exception for rid "
+                            f"{req.rid}"))
+                        continue
+                sampled[i] = req
+            if any(r is not None for r in sampled):
+                # every row of the step in one program over the full
+                # (B, 1, V) logits (one compiled shape), read once; the
+                # non-finite guard stays per row
+                self.counters["sample_calls"] += 1
                 try:
-                    if f is not None and f.kind in ("nan_logits",
-                                                    "inf_logits"):
-                        row = self.injector.corrupt_logits(row, f.kind)
-                    sfault = (self.injector.poll(req.rid, "sampling")
-                              if self.injector is not None else None)
-                    if sfault is not None:
-                        if sfault.kind == "slow_step":
-                            self.injector.sleep(sfault.seconds)
-                        elif sfault.kind == "process_crash":
-                            # BaseException: escapes this per-request guard
-                            # AND the step — nothing below commits
-                            raise SimulatedCrash(
-                                f"simulated crash at sampling of rid "
-                                f"{req.rid}")
-                        elif sfault.kind == "exception":
-                            raise InjectedFault(
-                                f"injected sampling exception for rid "
-                                f"{req.rid}")
-                    outcomes[i] = ("ok", int(self._sample(req, row)[0]))
-                except Exception as e:  # isolated: fails only this request
-                    outcomes[i] = ("fail", e)
+                    got = self._sample_rows(logits, sampled)
+                except Exception as e:
+                    # the one sampling call died: every row it held gets a
+                    # (retryable) failed attempt, as for the step's call
+                    got = [e] * self.b
+                for i, req in enumerate(sampled):
+                    if req is not None:
+                        outcomes[i] = ("fail" if isinstance(got[i], Exception)
+                                       else "ok", got[i])
             ssp.set_metadata(rows=len(included))
 
         with span("serve.commit") as csp:
@@ -1128,7 +1142,7 @@ class ServeEngine:
                     elif sfault.kind == "exception":
                         raise InjectedFault(
                             f"injected sampling exception for rid {req.rid}")
-                tok = int(self._sample(req, logits[:, -1])[0])
+                tok = int(self._sample(req, logits)[0])
             except Exception as e:  # isolated: fails only this request
                 self._attempt_failed(i, req, e)
                 progressed = True
@@ -1162,22 +1176,44 @@ class ServeEngine:
             self.sleep_fn(self.retry_backoff_s * (2 ** streak))
 
     def _check_finite(self, logits):
-        if not bool(jnp.isfinite(logits).all()):
-            n_nan = int(jnp.isnan(logits).sum())
-            n_inf = int(jnp.isinf(logits).sum())
-            raise NonFiniteLogitsError(
-                f"non-finite logits at prefill-chunk boundary: {n_nan} NaN, "
-                f"{n_inf} Inf of {logits.size} entries")
+        n_nan, n_inf = jax.device_get(count_non_finite(logits))
+        if n_nan or n_inf:
+            raise non_finite_error("prefill-chunk", int(n_nan), int(n_inf),
+                                   logits.size)
+
+    def _sample_rows(self, logits, reqs):
+        """One sampling program over ``logits`` ((B, V), or (B, S, V)
+        sampled at each row's last position) and one host read.
+        ``reqs[b]`` is row b's request, or None for a row whose result
+        nobody reads.  Returns, per row, its token (int), its
+        :class:`NonFiniteLogitsError` if the row holds NaN or Inf, or None
+        where there is no request.
+
+        Keys depend only on (engine seed, rid, token index): a request's
+        tokens are invariant to slot placement, co-tenants, page layout,
+        and retries — the property the chaos suite's bitwise-parity
+        asserts rely on."""
+        rows = np.zeros((len(reqs), 3), np.uint32)
+        for b, req in enumerate(reqs):
+            if req is not None:
+                rows[b] = (req.rid, len(req.out_tokens),
+                           np.float32(req.temperature).view(np.uint32))
+        toks, n_nan, n_inf = jax.device_get(
+            sample_rows_packed(logits, self.base_key, rows))
+        return [None if req is None
+                else non_finite_error("sampling", int(n_nan[b]),
+                                      int(n_inf[b]), logits.shape[-1])
+                if n_nan[b] or n_inf[b] else int(toks[b])
+                for b, req in enumerate(reqs)]
 
     def _sample(self, req: Request, logits):
-        # key depends only on (engine seed, rid, token index): a request's
-        # tokens are invariant to slot placement, co-tenants, page layout,
-        # and retries — the property the chaos suite's bitwise-parity
-        # asserts rely on
-        key = jax.random.fold_in(
-            jax.random.fold_in(self.base_key, req.rid), len(req.out_tokens))
-        return sample_token(logits, key, temperature=req.temperature,
-                            check_finite=True)
+        """One request's token from ``logits`` ((1, V), or (1, S, V) sampled
+        at its last position), as a (1,) int32 array; raises
+        :class:`NonFiniteLogitsError` on NaN or Inf."""
+        got, = self._sample_rows(logits, [req])
+        if isinstance(got, NonFiniteLogitsError):
+            raise got
+        return np.asarray([got], np.int32)
 
     def _should_finish(self, req: Request, tok: int) -> bool:
         total = len(req.prompt) + len(req.out_tokens)

@@ -155,3 +155,17 @@ def test_paged_step_compiles_without_f64(one_chip, b, s):
         text = jax.jit(step).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "f64[" not in text  # no 64-bit float value of any shape
+
+
+@pytest.mark.parametrize("b,vocab", [(64, 49152), (8, 32064), (1, 49152)],
+                         ids=["smollm-decode", "phi3-decode", "one-row"])
+def test_sample_rows_compiles(one_chip, b, vocab):
+    """The engine's one sampling program per step, over the step's
+    (B, 1, V) logits at the benchmark cells' decode batches and
+    vocabularies, and at the one row of a final chunk."""
+    from repro.serve.sampling import sample_rows_packed
+
+    args = (_sds((b, 1, vocab), jnp.float32, one_chip),
+            _sds((2,), jnp.uint32, one_chip),
+            _sds((b, 3), jnp.uint32, one_chip))
+    sample_rows_packed.lower(*args).compile()

@@ -84,6 +84,10 @@ def test_engine_spans_under_the_profiler(tmp_path, rng):
     assert sum(a["tokens"] for *_, a in chunks) == sum(map(len, prompts))
     assert {a["rid"] for *_, a in chunks} == {0, 1, 2}
     assert sum(a["admitted"] for *_, a in by("serve.admit")) == 3
+    # one batched sampling call per decode step
+    assert len(by("serve.sample")) == len(decode)
+    assert all(sum(_within(s, d) for s in by("serve.sample")) == 1
+               for d in decode)
     assert sum(a["rows"] for *_, a in by("serve.sample")) == sum(
         a["tokens"] for *_, a in by("serve.commit")) == 3 * 4
 
