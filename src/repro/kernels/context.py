@@ -133,6 +133,7 @@ def fused_vmem_bytes(k: int, r: int, bm: int, bn: int, bk: int, br: int,
         bm * k_pad          # xq int8 residency
         + bm * n_s * 4      # sx (per-token column or per-group scale plane)
         + bm * bn * 4       # GEMM accumulator (int32 or grouped f32)
+        + bk * bk           # int8 even/odd permutation of an xq chunk
     )
     if r:
         res += bm * r_pad * 4  # xv accumulator

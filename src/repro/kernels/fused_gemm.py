@@ -43,7 +43,8 @@ Per grid step (i, j, kk, rr), K_pad = K rounded up to bk, R_pad to br:
 
   j == 0          : prologue sweep (see above); no output write
   j >= 1, rr == 0 : int8×int8→int32 partial sum of xq[:, kk·bk:] against the
-                    streamed weight chunk into the acc scratch
+                    streamed weight chunk's two nibble planes into the acc
+                    scratch (see NIBBLE PLANES below)
   j == 1          : xv[:, rr·br:] += x_rot chunk · V tile  (projection rides
                     the first GEMM visit, when V streams)
   last (kk, rr)   : epilogue acc·sx·sw (+ xv Uᵀ) → one HBM write of the
@@ -54,6 +55,17 @@ amax/quantize/project; rotation requires K = K_pad, power of two), so the
 integer accumulation over padded chunks is exact and all paths stay bitwise
 identical in interpret mode.
 
+NIBBLE PLANES: packed byte row r of a weight chunk holds K rows 2r (low
+nibble) and 2r+1 (high nibble).  The GEMM step never interleaves them back
+into K order (a sublane gather/rotate/select per element on every grid
+step): it sign-extends the two (bk/2, bn) planes by shifts and contracts
+each against the matching half of the xq chunk, which the prologue stores
+once per M tile as ``[even K columns | odd K columns]`` — an exact int8 dot
+with a 0/1 permutation, since Mosaic refuses the lane-strided load.  Int32
+sums are exact in any order, so the output keeps its bits.  The packed
+weight layout is untouched.  ``traces`` counts the kernel's traces by
+spelling.
+
 GROUP-WISE activation scales (``act_group``, paper Table 2 g = 128): the
 (bm, 1) per-token scale becomes the (bm, K_pad/g) scale plane in the same
 VMEM scratch, with bk a multiple of g so a K-chunk always holds whole
@@ -63,7 +75,9 @@ fold entirely), and the dequant moves from the epilogue into the K loop:
 each GEMM chunk's int32 group partials rescale by their group's activation
 scale before the f32 accumulation (``rowops.gemm_chunk_grouped``, the
 canonical order shared with the chained/unfused GEMM), so the accumulator
-scratch is f32 and the epilogue multiplies only the weight scales.
+scratch is f32 and the epilogue multiplies only the weight scales.  A
+group's rows stay contiguous in each nibble plane, so its partial is the
+sum of two exact plane partials (``rowops.gemm_chunk_grouped_planes``).
 """
 
 from __future__ import annotations
@@ -78,20 +92,26 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.rowops import (
     amax_to_scale,
     default_proj_tiles,
+    even_odd_perm,
     f32_dot,
     fwht_cross_rows,
     fwht_intra_rows,
-    gemm_chunk_grouped,
+    gemm_chunk_grouped_planes,
+    gemm_chunk_planes,
     group_amax,
-    int_dot,
     project_chunk_rows,
     quantize_rows,
     quantize_rows_grouped,
     row_amax,
-    unpack_int4_rows,
+    split_even_odd,
 )
 
 _VARIANTS = ("resident", "streamed")
+
+# Kernel traces by the GEMM step's unpack spelling (counted at trace time,
+# not per call): "planes" contracts the packed chunk's two nibble planes
+# against the even/odd-split xq chunk, for every scale group.
+traces = {"planes": 0}
 
 
 def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
@@ -118,10 +138,18 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
             if rotate:
                 row = fwht_cross_rows(row, k_pad, bk)
                 rot_s[...] = row
+            perm = even_odd_perm(bk)
             if group is None:
                 s = amax_to_scale(row_amax(row), qmax, clip_ratio)
                 sx_s[...] = s
-                xq_s[...] = quantize_rows(row, s, qmax)
+
+                def put(c, carry):
+                    cols = pl.ds(pl.multiple_of(c * bk, bk), bk)
+                    xq_s[:, cols] = split_even_odd(
+                        quantize_rows(rot_s[:, cols], s, qmax), perm)
+                    return carry
+
+                jax.lax.fori_loop(0, n_k, put, 0)
             else:
                 # groups never cross a chunk: scale and quantize chunk by
                 # chunk into the (n_k, bm, bk // g) scale-plane scratch
@@ -130,8 +158,8 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
                     s = amax_to_scale(group_amax(chunk, group), qmax,
                                       clip_ratio)
                     sx_s[c] = s
-                    xq_s[:, c * bk:(c + 1) * bk] = quantize_rows_grouped(
-                        chunk, s, qmax, group)
+                    xq_s[:, c * bk:(c + 1) * bk] = split_even_odd(
+                        quantize_rows_grouped(chunk, s, qmax, group), perm)
     elif group is None:
         @pl.when((j == 0) & (rr == 0))
         def _fold_amax():
@@ -144,8 +172,9 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
 
         @pl.when((j == 1) & (rr == 0))
         def _quantize_chunk():
-            xq_s[:, pl.ds(kk * bk, bk)] = quantize_rows(
-                x_ref[...].astype(jnp.float32), sx_s[...], qmax)
+            xq_s[:, pl.ds(kk * bk, bk)] = split_even_odd(quantize_rows(
+                x_ref[...].astype(jnp.float32), sx_s[...], qmax),
+                even_odd_perm(bk))
     else:
         # streamed + grouped: groups never cross a chunk, so each chunk's
         # scales finalize chunk-locally on the sweep — no cross-chunk fold
@@ -156,8 +185,10 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
 
         @pl.when((j == 1) & (rr == 0))
         def _quantize_chunk_grouped():
-            xq_s[:, pl.ds(kk * bk, bk)] = quantize_rows_grouped(
-                x_ref[...].astype(jnp.float32), sx_s[kk], qmax, group)
+            xq_s[:, pl.ds(kk * bk, bk)] = split_even_odd(
+                quantize_rows_grouped(x_ref[...].astype(jnp.float32),
+                                      sx_s[kk], qmax, group),
+                even_odd_perm(bk))
 
     # ---- low-rank projection rides the first GEMM visit (V streams) -----
     if xv_s is not None:
@@ -176,14 +207,16 @@ def _body(x_ref, v_ref, wp_ref, sw_ref, u_ref, out_ref,
         def _zero():
             acc_s[...] = jnp.zeros_like(acc_s)
 
-        w_blk = unpack_int4_rows(wp_ref[...])
+        # the chunk's nibble planes against its even/odd xq halves: no
+        # sublane interleave back into K order
+        xq = xq_s[:, pl.ds(kk * bk, bk)]
         if group is None:
-            acc_s[...] += int_dot(xq_s[:, pl.ds(kk * bk, bk)], w_blk)
+            acc_s[...] += gemm_chunk_planes(xq, wp_ref[...])
         else:
             # dequant in the K loop: the chunk's groups rescale before the
             # f32 accumulation (canonical gemm_chunk_grouped order)
-            acc_s[...] += gemm_chunk_grouped(
-                xq_s[:, pl.ds(kk * bk, bk)], w_blk, sx_s[kk], group)
+            acc_s[...] += gemm_chunk_grouped_planes(xq, wp_ref[...],
+                                                    sx_s[kk], group)
 
     # ---- epilogue: one HBM write per (M-tile, N-tile) --------------------
     @pl.when((j >= 1) & last_kr)
@@ -242,6 +275,7 @@ def fused_w4a4_lrc_kernel(
         assert bk % act_group == 0, (bk, act_group)
     qmax = 2 ** (bits - 1) - 1
     with_lr = v is not None
+    traces["planes"] += 1
 
     if k_pad > k:
         x = jnp.pad(x, ((0, 0), (0, k_pad - k)))

@@ -339,6 +339,72 @@ def dequant_rows_grouped(q: jnp.ndarray, s: jnp.ndarray,
     return x.reshape(bm, d)
 
 
+def even_odd_perm(bk: int) -> jnp.ndarray:
+    """(bk, bk) int8 0/1 permutation taking a chunk's K columns to
+    ``[0, 2, 4, … | 1, 3, 5, …]`` under a right-multiply."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+    src = jnp.where(col < bk // 2, 2 * col, 2 * col - bk + 1)
+    return (row == src).astype(jnp.int8)
+
+
+def split_even_odd(q: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
+    """(bm, bk) int8 K-chunk -> ``[q[:, 0::2] | q[:, 1::2]]``, the activation
+    layout the nibble planes of :func:`unpack_int4_planes` contract against.
+    An exact int8 dot with ``perm = even_odd_perm(bk)``: Mosaic cannot
+    legalize the lane-strided load that would say it directly."""
+    return int_dot(q, perm).astype(jnp.int8)
+
+
+def unpack_int4_planes(wp: jnp.ndarray):
+    """(BK//2, BN) uint8 -> the two (BK//2, BN) int8 nibble planes in
+    [-8, 7]: ``lo`` holds the chunk's K rows 0, 2, 4, …, ``hi`` rows
+    1, 3, 5, ….  Each nibble is sign-extended by two int32 shifts, and the
+    planes are never interleaved back into K order, so
+    ``int_dot(xe, lo) + int_dot(xo, hi)`` over the even/odd halves of
+    :func:`split_even_odd` is the chunk's exact int32 GEMM partial."""
+    w = wp.astype(jnp.int32)
+    lo = (w << 28) >> 28
+    hi = (w << 24) >> 28
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
+
+
+def gemm_chunk_planes(xq_chunk: jnp.ndarray, wp: jnp.ndarray) -> jnp.ndarray:
+    """ONE K-chunk of the per-token int4 GEMM from the packed bytes, without
+    rebuilding K order: xq_chunk (bm, bk) int8 laid out by
+    :func:`split_even_odd`, wp the (bk//2, bn) uint8 chunk.  Exact int32
+    sums, so equal to ``int_dot(xq, unpack_int4_rows(wp))`` bit for bit."""
+    h = xq_chunk.shape[1] // 2
+    lo, hi = unpack_int4_planes(wp)
+    return int_dot(xq_chunk[:, :h], lo) + int_dot(xq_chunk[:, h:], hi)
+
+
+def gemm_chunk_grouped_planes(xq_chunk: jnp.ndarray, wp: jnp.ndarray,
+                              s_chunk: jnp.ndarray,
+                              group: int) -> jnp.ndarray:
+    """:func:`gemm_chunk_grouped` from the packed bytes, without rebuilding
+    K order: xq_chunk (bm, bk) int8 laid out by :func:`split_even_odd`, wp
+    the (bk//2, bn) uint8 chunk, s_chunk (bm, bk // group) f32.  Group gi's
+    K rows [gi·g, (gi+1)·g) are plane rows [⌈gi·g/2⌉, ⌈(gi+1)·g/2⌉) of the
+    even plane and [⌊gi·g/2⌋, ⌊(gi+1)·g/2⌋) of the odd one (for an even g
+    both are g/2 rows from gi·g/2), so each group's int32 partial is the
+    exact sum of two plane partials and the rescale-and-sum keeps
+    :func:`gemm_chunk_grouped`'s ascending order bit for bit."""
+    h = xq_chunk.shape[1] // 2
+    xe, xo = xq_chunk[:, :h], xq_chunk[:, h:]
+    lo, hi = unpack_int4_planes(wp)
+    out = None
+    for gi in range(xq_chunk.shape[1] // group):  # ascending K
+        a, b = gi * group, (gi + 1) * group
+        ev = slice(-(-a // 2), -(-b // 2))
+        od = slice(a // 2, b // 2)
+        part = (int_dot(xe[:, ev], lo[ev, :])
+                + int_dot(xo[:, od], hi[od, :]))
+        term = part.astype(jnp.float32) * s_chunk[:, gi:gi + 1]
+        out = term if out is None else out + term
+    return out
+
+
 def unpack_int4_rows(wp: jnp.ndarray) -> jnp.ndarray:
     """(BK//2, BN) uint8 -> (BK, BN) int8 in [-8, 7]; even rows = low nibble.
     Packed rows interleave (2i, 2i+1): stack on a new axis, then fold.  The

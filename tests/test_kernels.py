@@ -16,6 +16,57 @@ from repro.kernels.w4a4 import w4a4_lowrank_matmul_kernel
 
 
 # ---------------------------------------------------------------------------
+# int4 nibble planes (kernels/rowops.py)
+# ---------------------------------------------------------------------------
+
+
+def test_nibble_planes_are_even_and_odd_rows_of_unpack():
+    """For every byte value, the low/high nibble planes are the even and
+    odd K rows of the interleaved unpack, and an even/odd-split activation
+    chunk contracted against them gives the interleaved GEMM's int32s."""
+    from repro.kernels.rowops import (even_odd_perm, gemm_chunk_planes,
+                                      int_dot, split_even_odd,
+                                      unpack_int4_planes, unpack_int4_rows)
+
+    wp = jnp.arange(256, dtype=jnp.uint8).reshape(2, 128)
+    wp = jnp.concatenate([wp, wp[::-1, ::-1]], axis=0)  # (4, 128): bk 8
+    lo, hi = unpack_int4_planes(wp)
+    rows = np.asarray(unpack_int4_rows(wp))
+    assert lo.dtype == hi.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(lo), rows[0::2])
+    np.testing.assert_array_equal(np.asarray(hi), rows[1::2])
+
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.integers(-8, 8, (16, 8)), jnp.int8)
+    split = np.asarray(split_even_odd(q, even_odd_perm(8)))
+    np.testing.assert_array_equal(
+        split, np.concatenate([q[:, 0::2], q[:, 1::2]], axis=1))
+    np.testing.assert_array_equal(
+        np.asarray(gemm_chunk_planes(jnp.asarray(split), wp)),
+        np.asarray(int_dot(q, unpack_int4_rows(wp))))
+
+
+@pytest.mark.parametrize("group", [1, 2, 3, 4, 6, 8, 24])
+def test_grouped_nibble_planes_bitwise_equal_interleaved(group):
+    """The grouped GEMM chunk from the nibble planes equals the canonical
+    K-order spelling bit for bit, for even groups (g/2 rows a plane) and
+    odd ones (whose rows straddle the planes)."""
+    from repro.kernels.rowops import (even_odd_perm, gemm_chunk_grouped,
+                                      gemm_chunk_grouped_planes,
+                                      split_even_odd, unpack_int4_rows)
+
+    bk = 24
+    rng = np.random.default_rng(group)
+    wp = jnp.asarray(rng.integers(0, 256, (bk // 2, 128)), jnp.uint8)
+    q = jnp.asarray(rng.integers(-8, 8, (16, bk)), jnp.int8)
+    s = jnp.asarray(rng.uniform(0.01, 1.0, (16, bk // group)), jnp.float32)
+    want = gemm_chunk_grouped(q, unpack_int4_rows(wp), s, group)
+    got = gemm_chunk_grouped_planes(split_even_odd(q, even_odd_perm(bk)),
+                                    wp, s, group)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
 # w4a4 fused matmul
 # ---------------------------------------------------------------------------
 

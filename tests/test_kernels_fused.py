@@ -54,6 +54,45 @@ def test_fused_bitwise_matches_chain_and_unfused(rng, m, k, n, r, rotate):
     np.testing.assert_allclose(outs["fused"], want, rtol=1e-4, atol=1e-4)
 
 
+def _plane_cases():
+    """(act_group, variant, bk, k, rotate): per-token and grouped, resident
+    and streamed, bk 256 and 512, K = k_pad (512) and K < k_pad (576);
+    rotation (power-of-two K, resident) at K = 512; an odd group, whose
+    rows straddle the two planes, at the one bk it snaps to."""
+    cases = [(g, var, bk, k, False)
+             for g in (None, 64) for var in ("resident", "streamed")
+             for bk in (256, 512) for k in (512, 576)]
+    cases += [(g, "resident", bk, 512, True)
+              for g in (None, 64) for bk in (256, 512)]
+    return cases + [(3, "resident", 384, 576, False)]
+
+
+@pytest.mark.parametrize("group,variant,bk,k,rotate", _plane_cases())
+def test_fused_nibble_planes_bitwise_equal_unfused(rng, group, variant, bk,
+                                                   k, rotate):
+    """The fused kernel's GEMM step contracts the packed chunk's nibble
+    planes against the even/odd-split xq chunk: exact int32 sums, so its
+    output equals the unfused path (interleaved unpack) bit for bit."""
+    from repro.kernels import fused_gemm
+
+    m, n, r, bm, bn, br = 16, 128, 8, 16, 128, 128
+    spec, x, wp, s, u, v = _problem(rng, m, k, n, r, act_group=group)
+    want = np.asarray(ops.w4a4_lrc_forward(
+        x, wp, s, u, v, spec, rotate=rotate, blocks=(bm, bn, bk, br),
+        impl="unfused"))
+    k_pad = k + (-k) % bk
+    wpp = jnp.pad(wp, ((0, (k_pad - k) // 2), (0, 0)))
+    fused_w4a4_lrc_kernel.clear_cache()
+    before = dict(fused_gemm.traces)
+    got = np.asarray(fused_w4a4_lrc_kernel(
+        x, v, wpp, s.reshape(1, -1), u, bits=4, clip_ratio=0.9,
+        rotate=rotate, bm=bm, bn=bn, bk=bk, br=br, variant=variant,
+        act_group=group, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    assert {key: fused_gemm.traces[key] - before[key] for key in before} \
+        == {"planes": 1}
+
+
 def test_fused_kernel_direct_block_aligned(rng):
     """The raw kernel (no wrapper padding) against the pure-jnp oracle."""
     m, k, n, r = 32, 128, 64, 8

@@ -1,6 +1,7 @@
 """Compile-only checks for a described TPU v5e: the W4A4+LRC kernels that
-``ops.w4a4_lrc_forward`` resolves to at smollm-135m's published layer
-widths, and one jitted ``paged_step`` of the quantized model at full width.
+``ops.w4a4_lrc_forward`` resolves to at smollm-135m's and phi3-mini's
+published layer widths, and one jitted ``paged_step`` of the quantized
+model at full width.
 
 Nothing runs here: the TPU compiler (installed with jaxlib) compiles for a
 ``v5e:2x2`` topology that is described, not attached, and refuses what the
@@ -28,6 +29,8 @@ from repro.quant.qlinear import QLinear
 CFG = get_config("smollm-135m")
 # (K, N, R) of every quantized layer at rank_frac=0.10: wq/wo, wk/wv, wg/wu, wd
 SHAPES = [(576, 576, 58), (576, 192, 19), (576, 1536, 58), (1536, 576, 58)]
+# phi3-mini at rank 307: attention and the MLP's up and down projections
+PHI3_SHAPES = [(3072, 3072, 307), (3072, 8192, 307), (8192, 3072, 307)]
 COMPILED = KernelContext(interpret=False)
 
 
@@ -74,6 +77,22 @@ def test_fused_per_token_compiles(one_chip, m, k, n, r):
     assert COMPILED.resolve_plan(m, k, n, r).path == "fused"
     compiled = _compile_forward(one_chip, m, k, n, r, "auto")
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m", [8, 64], ids=["decode", "chunk"])
+@pytest.mark.parametrize("k,n,r", PHI3_SHAPES)
+def test_fused_phi3_widths_compiles(one_chip, m, k, n, r):
+    """The plans phi3-mini's decode steps and 64-token chunks resolve to,
+    with the GEMM step on the nibble planes: a lowering Mosaic refuses
+    (a lane-strided load, say) shows only here, not in interpret mode."""
+    from repro.kernels import fused_gemm
+
+    assert COMPILED.resolve_plan(m, k, n, r).path == "fused"
+    fused_gemm.fused_w4a4_lrc_kernel.clear_cache()
+    before = fused_gemm.traces["planes"]
+    compiled = _compile_forward(one_chip, m, k, n, r, "auto")
+    assert "tpu_custom_call" in compiled.as_text()
+    assert fused_gemm.traces["planes"] == before + 1
 
 
 @pytest.mark.parametrize("m", [8, 200], ids=["decode", "mixed"])
